@@ -68,8 +68,11 @@ def main() -> None:
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
 
+    from repro.core.platform import enable_compile_cache
+
     from .common import emit
 
+    enable_compile_cache()
     print("name,us_per_call,derived")
     failures, ran = [], []
     for name, what, module in BENCHES:

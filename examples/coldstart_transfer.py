@@ -140,7 +140,7 @@ def main():
         from repro.workloads.suite import suite
 
         coll = StreamingCollector(
-            store, suite(sizes=("s",))[:4], repeats=2, measure_cpu=False,
+            store, suite(sizes=("s",))[:4], repeats=2, measure=False,
             seed=11, chunk_size=4)
         coll.add_on_chunk(sup.on_chunk)   # poke, don't poll
         with sup:                         # background supervision loop

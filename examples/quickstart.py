@@ -27,7 +27,7 @@ from repro.workloads.suite import suite
 def main():
     print("collecting workloads (features once + CPU wall-clock)...")
     workloads = suite(sizes=("s", "m"))
-    ds = collect(workloads, repeats=5, measure_cpu=True,
+    ds = collect(workloads, repeats=5, measure=True,
                  progress=lambda m: print(m))
     X, y, kept = ds.matrix("cpu-host", "time_us")
     print(f"dataset: {len(y)} kernels, {y.min():.0f}..{y.max():.0f} us")

@@ -36,7 +36,7 @@ def main():
 
     print(f"== bootstrap: measure the first workloads ({device}) ==")
     bootstrap, rest = workloads[:24], workloads[24:]
-    store.extend(list(iter_samples(bootstrap, repeats=3, measure_cpu=False,
+    store.extend(list(iter_samples(bootstrap, repeats=3, measure=False,
                                    seed=0)))
     fit = single_device_fit_fn(device, n_estimators=32)
     snap = store.snapshot()
@@ -47,7 +47,7 @@ def main():
     print("== stream the rest while serving ==")
     X0, _, _ = snap.dataset.matrix(device, "time_us")
     X0 = X0.astype(np.float32)
-    collector = StreamingCollector(store, rest, repeats=3, measure_cpu=False,
+    collector = StreamingCollector(store, rest, repeats=3, measure=False,
                                    seed=0, chunk_size=16)
     refresher = EngineRefresher(store, eng, fit, poll_s=0.02)
     served = 0

@@ -8,9 +8,11 @@ with tight deadlines, a batch tenant with none, a best-effort tenant pinned
 to a low priority), serializes it to the CRC-tagged JSONL format, reloads
 it — the round trip is the point: what gets replayed is the ARTIFACT, not
 in-memory state — and drives a demo frontend at recorded timestamps with
-open-loop pacing. With ``--wire`` the same trace is replayed a second time
-against a ``repro.cluster`` server SUBPROCESS over loopback TCP (the PR-4
-wire), showing that the replayer drives both target shapes unchanged.
+open-loop pacing. With ``--wire`` the same frontend is also put behind a
+``PredictionServer`` on loopback TCP and the trace is replayed a second
+time through a ``RemoteReplica``, showing that the replayer drives both
+target shapes unchanged. Both replays run in this one process, which is
+the only one that touches the device.
 
 The final lines print each tenant's served/shed/expired counts, observed
 wall-clock percentiles, and the deterministic outcome digest — the same
@@ -23,7 +25,7 @@ from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 
-from repro.cluster.remote import demo_frontend, spawn_demo_server  # noqa: E402
+from repro.cluster.remote import demo_frontend  # noqa: E402
 from repro.workloads.trace import (TraceReplayer, dump_trace,  # noqa: E402
                                    gen_tenant_mix, load_trace,
                                    synthetic_catalog)
@@ -62,8 +64,8 @@ def print_report(label: str, rep) -> None:
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--wire", action="store_true",
-                    help="also replay over loopback TCP against a server "
-                         "subprocess")
+                    help="also replay over loopback TCP through a "
+                         "PredictionServer in this process")
     ap.add_argument("--speed", type=float, default=4.0,
                     help="replay speedup over recorded time (default 4x)")
     args = ap.parse_args()
@@ -74,23 +76,17 @@ def main() -> int:
     fe = demo_frontend(seed=3, n_features=N_FEATURES).start()
     try:
         rep = TraceReplayer(fe, pacing="open", speed=args.speed).replay(trace)
+        print_report("in-process frontend", rep)
+        if args.wire:
+            from repro.cluster import PredictionServer, RemoteReplica
+
+            with PredictionServer(fe, port=0) as server, \
+                    RemoteReplica(server.address, timeout_s=30.0) as replica:
+                rep = TraceReplayer(replica, pacing="open",
+                                    speed=args.speed).replay(trace)
+            print_report("over the loopback wire", rep)
     finally:
         fe.close()
-    print_report("in-process frontend", rep)
-
-    if args.wire:
-        from repro.cluster import RemoteReplica
-
-        proc, host, port = spawn_demo_server(seed=3, n_features=N_FEATURES)
-        try:
-            replica = RemoteReplica((host, port), timeout_s=30.0)
-            rep = TraceReplayer(replica, pacing="open",
-                                speed=args.speed).replay(trace)
-            replica.close()
-        finally:
-            proc.terminate()
-            proc.wait(timeout=10)
-        print_report("over the PR-4 wire", rep)
     return 0
 
 

@@ -1109,12 +1109,14 @@ def demo_estimator(seed: int = 0, n_features: int = 6, n_trees: int = 24,
 def demo_frontend(seed: int = 0, n_features: int = 6, n_trees: int = 24,
                   *, max_queue: int = 256,
                   obs: Observability | None = None) -> ClusterFrontend:
-    """One-replica frontend over ``demo_estimator`` (CLI + selftest)."""
+    """One-replica frontend over ``demo_estimator`` (CLI + selftest). It
+    serves ``flat-jax``, the exact path on JAX's default device — the chip
+    where there is one — so the process that builds it holds that device."""
     from ..serve import ForestEngine
     from .replicas import ReplicaPool
 
     est = demo_estimator(seed=seed, n_features=n_features, n_trees=n_trees)
-    engine = ForestEngine(est, backend="flat-numpy", cache_size=0)
+    engine = ForestEngine(est, backend="flat-jax", cache_size=0)
     pool = ReplicaPool({"local": engine}, check_interval_s=1.0)
     if obs is not None:
         engine.register_metrics(obs.registry, replica="local")
@@ -1133,6 +1135,11 @@ def spawn_demo_server(port: int = 0, *, seed: int = 0, trees: int = 24,
     The one place that knows the CLI flags, the PYTHONPATH wiring, and the
     startup handshake — shared by the ``--selftest`` smoke, the transport
     tests' kill/restart drills, and ``examples/remote_serve.py``.
+
+    The child serves from JAX's default device, and a chip belongs to one
+    process: a caller that must leave the chip to the child never
+    initializes a JAX backend itself (numpy engines and ``est.predict``
+    are fine).
     """
     import subprocess
     import sys
@@ -1356,6 +1363,9 @@ def main(argv: list[str] | None = None) -> int:
     if args.stats:
         return _print_stats(args)
 
+    from ..core.platform import enable_compile_cache
+
+    enable_compile_cache()
     obs = Observability.default()
     frontend = demo_frontend(seed=args.seed, n_features=args.n_features,
                              n_trees=args.trees, obs=obs)

@@ -3,16 +3,16 @@
 Two layouts:
 
 1. ``FlatForest`` (exact): sparse node arrays + gather-based traversal.
-   Works for unbounded-depth trees; jit-compiled; used on CPU hosts and as
-   the reference for the Pallas path.
+   Works for unbounded-depth trees; jit-compiled; the exact device path on
+   any platform, the TPU included.
 
-2. ``DenseForest`` (TPU-native): every tree is embedded into a *complete*
-   binary tree of fixed depth D (child index = 2i+1 / 2i+2, no child
-   pointers). Traversal is level-synchronous, and on TPU the node lookup is
-   expressed as one-hot contractions (see ``kernels/forest``) — zero dynamic
-   gathers, pure MXU/VPU work. Trees deeper than D are truncated: the cut
-   subtree is replaced by its node value (the node's training-set mean), a
-   bounded, measured approximation (see tests / EXPERIMENTS.md §Perf).
+2. ``DenseForest``: every tree is embedded into a *complete* binary tree
+   of fixed depth D (child index = 2i+1 / 2i+2, no child pointers).
+   Traversal is level-synchronous; on TPU the Pallas kernel
+   (``kernels/forest``) reads each level's nodes as vector selects, with no
+   dynamic gathers. Trees deeper than D are truncated: the cut subtree is
+   replaced by its node value (the node's training-set mean), which is why
+   auto backend selection leaves this layout out for deeper forests.
 """
 from __future__ import annotations
 
@@ -121,16 +121,20 @@ def to_dense(est: ExtraTreesRegressor, depth: int,
                        depth=depth, n_features=est.n_features_)
 
 
-def dense_leaf_sum(feature, threshold, value, x, depth: int):
+def dense_leaf_sum(feature, threshold, value, x, depth: int,
+                   axis_name: str | None = None):
     """SUM of per-tree leaf values, (B,) — the shard-combinable core of dense
     traversal. Inert (padded) trees carry value 0 everywhere and contribute
     nothing, so a partitioned forest's prediction is
     ``sum(shard sums) / n_real_trees`` — a psum across shards when the tree
     axis is device-partitioned (``serve/sharded.py``). Traceable: call from
-    inside jit / shard_map."""
+    inside jit / shard_map; inside ``shard_map`` pass the tree mesh axis as
+    ``axis_name``, over which the traversal state varies."""
     B = x.shape[0]
     T = feature.shape[0]
     cur = jnp.zeros((B, T), dtype=jnp.int32)
+    if axis_name is not None:
+        cur = jax.lax.pcast(cur, axis_name, to="varying")
     trees = jnp.arange(T)[None, :]
 
     def body(_, cur):

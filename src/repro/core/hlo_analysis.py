@@ -45,16 +45,6 @@ COLLECTIVE_OPS = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
                   "collective-permute")
 
 
-def xla_cost_analysis(compiled) -> dict:
-    """``compiled.cost_analysis()`` normalized across jax versions: older
-    builds (<= 0.4.x) return a one-element list of per-program dicts, newer
-    ones a plain dict. Always returns a dict (possibly empty)."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca) if ca else {}
-
-
 def _shapes_bytes(text: str) -> float:
     """Total bytes of all shapes mentioned in a type string."""
     total = 0.0

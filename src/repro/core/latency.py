@@ -8,7 +8,7 @@ same quantity for every inference path in this repo:
   * ``flat-numpy`` : vectorized flattened-forest numpy
   * ``flat-jax``   : jit-compiled gather traversal
   * ``dense-jax``  : complete-tree layout (the Pallas kernel's oracle)
-  * ``pallas``     : the MXU one-hot kernel (interpret=True on CPU)
+  * ``pallas``     : the dense-layout Pallas kernel (interpreted off-TPU)
 
 producing the paper-faithful baseline AND the beyond-paper hillclimb in one
 table (EXPERIMENTS.md §Perf).
@@ -63,16 +63,9 @@ def calibrate_backends(fns: dict, x_batch: np.ndarray,
                        warmup: int = 1, iters: int = 3) -> dict[str, float]:
     """Self-calibration pass for the serving engine: time every candidate
     inference path on one flush-sized batch (the engine's unit of work) and
-    return {name: seconds}. Backends that fail to run (e.g. Pallas lowering
-    on an unsupported host) score +inf rather than raising, so auto-selection
-    degrades gracefully."""
-    scores: dict[str, float] = {}
-    for name, fn in fns.items():
-        try:
-            scores[name] = time_call(fn, x_batch, warmup=warmup, iters=iters)
-        except Exception:
-            scores[name] = float("inf")
-    return scores
+    return {name: seconds}. A path that fails to run raises."""
+    return {name: time_call(fn, x_batch, warmup=warmup, iters=iters)
+            for name, fn in fns.items()}
 
 
 def measure_paths(est, X: np.ndarray, batch: int = 256,
@@ -105,16 +98,8 @@ def measure_paths(est, X: np.ndarray, batch: int = 256,
     out.append(LatencyResult("dense-jax", s, b, batch))
 
     if include_pallas:
-        from ..kernels.forest.ops import forest_predict
-        import jax.numpy as jnp
-        feat = jnp.asarray(dense.feature)
-        thr = jnp.asarray(dense.threshold)
-        val = jnp.asarray(dense.value)
-
-        def pal(x):
-            return np.asarray(forest_predict(
-                jnp.asarray(x, dtype=jnp.float32), feat, thr, val,
-                depth=dense.depth))
-        s, b = _bench(pal, x1, xb)
-        out.append(LatencyResult("pallas-interp", s, b, batch))
+        from ..kernels.forest import PallasForest
+        pf = PallasForest.from_dense(dense)
+        s, b = _bench(lambda x: np.asarray(pf(x)), x1, xb)
+        out.append(LatencyResult("pallas", s, b, batch))
     return out

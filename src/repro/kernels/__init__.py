@@ -1,11 +1,12 @@
 """Pallas TPU kernels for the framework's compute hot spots.
 
 Each kernel ships as a triple: ``kernel.py`` (pl.pallas_call + BlockSpec),
-``ops.py`` (jit'd public wrapper with padding/interpret switch), ``ref.py``
-(pure-jnp oracle used by the allclose test sweeps).
+``ops.py`` (public wrapper with padding), ``ref.py`` (pure-jnp oracle used
+by the allclose test sweeps). Kernels run compiled on a TPU and interpreted
+elsewhere; ``core.platform.pallas_interpret`` alone decides which.
 
-  forest/    MXU one-hot random-forest inference (the paper's prediction
-             latency hot spot, §7.1 — ms -> us)
+  forest/    level-synchronous random-forest inference (the paper's
+             prediction latency hot spot, §7.1 — ms -> us)
   attention/ flash attention (prefill hot spot)
   mamba/     chunked SSD scan (Mamba2/zamba2 + long-context)
 """

@@ -63,7 +63,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     "kv_len"))
 def flash_attention_kernel(q, k, v, *, causal: bool, sm_scale: float,
                            block_q: int, block_k: int, kv_len: int,
-                           kv_offset: int = 0, interpret: bool = True):
+                           kv_offset: int = 0, interpret: bool):
     """q: (B, Hq, Sq, D); k/v: (B, Hkv, Skv, D). Sq % block_q == 0,
     Skv % block_k == 0 (ops.py pads; keys at index >= kv_len are masked).
     kv_offset is the causal position of q row 0 (computed by ops.py from the

@@ -1,9 +1,11 @@
-"""jit'd public wrapper for flash attention: padding + interpret switch."""
+"""Public wrapper for flash attention: padding; the platform decides
+whether the kernel runs interpreted (``core.platform.pallas_interpret``)."""
 from __future__ import annotations
 
 import jax.numpy as jnp
 import numpy as np
 
+from ...core.platform import pallas_interpret
 from .kernel import flash_attention_kernel
 
 
@@ -18,12 +20,12 @@ def _pad_axis(a, size: int, axis: int):
 
 def flash_attention(q, k, v, *, causal: bool = True,
                     sm_scale: float | None = None,
-                    block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True):
+                    block_q: int = 128, block_k: int = 128):
     """q: (B, Hq, Sq, D); k/v: (B, Hkv, Skv, D). Returns (B, Hq, Sq, D).
 
     Pads Sq/Skv up to tile multiples and D up to a lane multiple; padded KV
     columns are masked out by the causal/key-validity mask."""
+    interpret = pallas_interpret()
     B, Hq, Sq, D = q.shape
     Skv = k.shape[2]
     if sm_scale is None:

@@ -1,4 +1,4 @@
-from .ops import forest_predict, forest_predict_from_dense
+from .ops import PallasForest, forest_predict
 from .ref import forest_predict_ref
 
-__all__ = ["forest_predict", "forest_predict_from_dense", "forest_predict_ref"]
+__all__ = ["PallasForest", "forest_predict", "forest_predict_ref"]

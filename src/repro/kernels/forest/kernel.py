@@ -1,29 +1,30 @@
-"""MXU-native random-forest inference (Pallas TPU kernel).
+"""Random-forest inference as a Pallas TPU kernel.
 
 The paper's deployment bottleneck is prediction latency: 15-108 ms per
 prediction for 256-1024 trees of average depth ~33 on a Xeon (paper Tables
-4/5), too slow for sub-millisecond scheduling (paper §7.1). GPU/CPU forest
-inference is pointer-chasing — hostile to the TPU's systolic design. This
-kernel re-thinks it (DESIGN.md §2, hardware-adaptation):
+4/5), too slow for sub-millisecond scheduling (paper §7.1). CPU forest
+inference is pointer-chasing; this kernel turns it into branch-free vector
+work:
 
-  * trees are *complete binary trees* of static depth D (dense layout, level
-    ``d`` occupies node slots [2^d-1, 2^{d+1}-1));
-  * traversal is level-synchronous: all (sample × tree) lanes advance one
-    level per step;
-  * the two irregular operations — "which feature does my current node test"
-    and "which threshold" — are expressed as ONE-HOT CONTRACTIONS against
-    the level's node table:
-        P[b,t,j]   = onehot(cur_index)                (VPU compare vs iota)
-        X_sel[b,t,j] = sum_f x[b,f] * onehot(feat)[t,j,f]   (MXU matmul)
-        bit[b,t]   = sum_j P[b,t,j] * (X_sel > thr)[b,t,j]  (VPU reduce)
-        cur        = 2*cur + 1 + bit
-    — zero dynamic gathers, 128-aligned contractions only.
+  * trees are *complete binary trees* of static depth D (the dense layout of
+    ``core/forest_jax.DenseForest``); traversal is level-synchronous, every
+    (sample, tree) pair advancing one level per step;
+  * trees lie on the 128 lanes and samples on the sublanes, so the state is
+    one int32 ``cur[b, t]`` (the level-local node index) per lane;
+  * "which feature and threshold does my node test" is a select over the
+    level's nodes: the node tables are read 8 rows (one vreg) at a time and
+    each row is kept where ``cur`` equals its index. Reading x at that
+    feature is the same select over the F features. No gathers, no float
+    rounding: the comparison ``x <= threshold`` is the tree-walk's own.
 
-Grid: (batch tiles, tree tiles), tree axis innermost; the output block is
-revisited across tree tiles and accumulated in-place (@pl.when(t == 0)
-initializes). Per-tile VMEM: x (BB,F) + 3 node tables (BT,N) + the level-D
-one-hot (BB,BT,2^D); with BB=8, BT=32, D<=10 that is ~4 MB — comfortably
-inside the ~16 MB VMEM budget, with MXU-aligned last dims.
+Node tables are node-major, ``(rows, trees)``, each level starting on a
+multiple of 8 rows (``level_offsets``) so that every load is tile-aligned.
+Grid: (tree tiles, batch tiles), batch innermost so a tree tile's tables
+are fetched once; the output holds one leaf value per (sample, tree) and
+the mean over trees is taken outside the kernel. Per step, VMEM holds the
+x tile, two internal-node tables and the leaf table: at D=10 and 128 trees
+that is 1040 + 1040 + 1024 rows of 512 bytes, ~1.6 MB, twice for double
+buffering.
 """
 from __future__ import annotations
 
@@ -33,78 +34,90 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+SUBLANES = 8      # node-table rows per load
+LANES = 128       # trees per tile
 
-def _forest_kernel(x_ref, feat_ref, thr_ref, val_ref, out_ref, *,
-                   depth: int, n_trees_total: int):
-    x = x_ref[...].astype(jnp.float32)              # (BB, F)
-    BB, F = x.shape
-    BT = feat_ref.shape[0]
 
-    cur = jnp.zeros((BB, BT), dtype=jnp.float32)    # level-local node index
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def level_offsets(depth: int) -> list[int]:
+    """Row where each level's nodes start in the internal-node tables; the
+    last entry is the tables' row count."""
+    offs = [0]
     for d in range(depth):
-        w = 2 ** d
-        off = w - 1
-        feat_d = feat_ref[:, off:off + w].astype(jnp.float32)   # (BT, w)
-        thr_d = thr_ref[:, off:off + w]                         # (BT, w)
-        # one-hot of current node within the level: (BB, BT, w)
-        lvl = jax.lax.broadcasted_iota(jnp.float32, (BB, BT, w), 2)
-        P = (lvl == cur[:, :, None]).astype(jnp.float32)
-        # one-hot of the node's tested feature: (BT, w, F)
-        fio = jax.lax.broadcasted_iota(jnp.float32, (BT, w, F), 2)
-        F1h = (fio == feat_d[:, :, None]).astype(jnp.float32)
-        # feature select as a contraction: (BB,F) x (BT,w,F) -> (BB,BT,w)
-        X_sel = jax.lax.dot_general(
-            x, F1h.reshape(BT * w, F),
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32).reshape(BB, BT, w)
-        go_right = (X_sel > thr_d[None, :, :]).astype(jnp.float32)
-        bit = jnp.sum(P * go_right, axis=2)                     # (BB, BT)
-        cur = 2.0 * cur + bit
+        offs.append(offs[-1] + _round_up(2 ** d, SUBLANES))
+    return offs
 
-    # leaf read at level `depth` via one final one-hot contraction
-    w = 2 ** depth
-    off = w - 1
-    val_d = val_ref[:, off:off + w]                             # (BT, w)
-    lvl = jax.lax.broadcasted_iota(jnp.float32, (BB, BT, w), 2)
-    P = (lvl == cur[:, :, None]).astype(jnp.float32)
-    acc = jnp.sum(P * val_d[None, :, :], axis=(1, 2)) / n_trees_total
 
-    t = pl.program_id(1)
+def leaf_rows(depth: int) -> int:
+    return _round_up(2 ** depth, SUBLANES)
 
-    @pl.when(t == 0)
-    def _init():
-        out_ref[...] = acc
 
-    @pl.when(t != 0)
-    def _acc():
-        out_ref[...] += acc
+def _select(cur, width: int, base: int, tables, fills):
+    """Per lane, the entry of each table at level-local node ``cur`` of a
+    level of ``width`` nodes starting at row ``base``."""
+    def chunk(c, acc):
+        start = pl.multiple_of(base + c * SUBLANES, SUBLANES)
+        rows = [t[pl.ds(start, SUBLANES), :] for t in tables]
+        for k in range(SUBLANES):
+            hit = cur == c * SUBLANES + k
+            acc = tuple(jnp.where(hit, r[k:k + 1, :], a)
+                        for r, a in zip(rows, acc))
+        return acc
+
+    init = tuple(jnp.full(cur.shape, f, t.dtype)
+                 for t, f in zip(tables, fills))
+    return jax.lax.fori_loop(0, -(-width // SUBLANES), chunk, init)
+
+
+def _forest_kernel(x_ref, feat_ref, thr_ref, leaf_ref, out_ref, *,
+                   depth: int):
+    x = x_ref[...]                                   # (BB, F) float32
+    offs = level_offsets(depth)
+    cur = jnp.zeros(out_ref.shape, jnp.int32)        # (BB, BT)
+    for d in range(depth):
+        feat, thr = _select(cur, 2 ** d, offs[d], (feat_ref, thr_ref),
+                            (-1, jnp.inf))
+        xv = jnp.zeros(cur.shape, jnp.float32)
+        for f in range(x.shape[1]):
+            xv = jnp.where(feat == f, x[:, f:f + 1], xv)
+        # terminal nodes carry feature -1 and threshold +inf: always left
+        cur = 2 * cur + jnp.where(xv <= thr, 0, 1)
+    (leaf,) = _select(cur, 2 ** depth, 0, (leaf_ref,), (0.0,))
+    out_ref[...] = leaf
 
 
 @functools.partial(
-    jax.jit,
-    static_argnames=("depth", "block_b", "block_t", "interpret", "n_trees_total"))
-def forest_predict_kernel(x, feature, threshold, value, *, depth: int,
-                          n_trees_total: int,
-                          block_b: int = 8, block_t: int = 32,
-                          interpret: bool = True):
-    """x: (B, F); feature/threshold/value: (T, N), N = 2^(depth+1)-1.
-    B, T must be multiples of block_b/block_t (ops.py pads)."""
+    jax.jit, static_argnames=("depth", "n_trees", "block_b", "interpret"))
+def forest_predict_kernel(x, feature, threshold, leaf, *, depth: int,
+                          n_trees: int, block_b: int, interpret: bool):
+    """Mean leaf value over the ``n_trees`` real trees, (B,) float32.
+
+    x: (B, F) float32. feature/threshold: (level_offsets(depth)[-1], Tp)
+    and leaf: (leaf_rows(depth), Tp), Tp a multiple of 128; padded trees
+    hold leaf value 0 (``ops.pack_tables`` builds all three)."""
     B, F = x.shape
-    T, N = feature.shape
-    assert N >= 2 ** (depth + 1) - 1, (N, depth)
-    assert B % block_b == 0 and T % block_t == 0, (B, T, block_b, block_t)
-    grid = (B // block_b, T // block_t)
-    return pl.pallas_call(
-        functools.partial(_forest_kernel, depth=depth,
-                          n_trees_total=n_trees_total),
-        grid=grid,
+    rows, Tp = feature.shape
+    assert rows == level_offsets(depth)[-1], (rows, depth)
+    assert leaf.shape == (leaf_rows(depth), Tp), (leaf.shape, depth)
+    assert Tp % LANES == 0, Tp
+    bb = min(block_b, _round_up(B, SUBLANES))
+    Bp = _round_up(B, bb)
+    x = jnp.pad(x, ((0, Bp - B), (0, 0)))
+    per_tree = pl.pallas_call(
+        functools.partial(_forest_kernel, depth=depth),
+        grid=(Tp // LANES, Bp // bb),
         in_specs=[
-            pl.BlockSpec((block_b, F), lambda i, t: (i, 0)),
-            pl.BlockSpec((block_t, N), lambda i, t: (t, 0)),
-            pl.BlockSpec((block_t, N), lambda i, t: (t, 0)),
-            pl.BlockSpec((block_t, N), lambda i, t: (t, 0)),
+            pl.BlockSpec((bb, F), lambda t, i: (i, 0)),
+            pl.BlockSpec((rows, LANES), lambda t, i: (0, t)),
+            pl.BlockSpec((rows, LANES), lambda t, i: (0, t)),
+            pl.BlockSpec((leaf.shape[0], LANES), lambda t, i: (0, t)),
         ],
-        out_specs=pl.BlockSpec((block_b,), lambda i, t: (i,)),
-        out_shape=jax.ShapeDtypeStruct((B,), jnp.float32),
+        out_specs=pl.BlockSpec((bb, LANES), lambda t, i: (i, t)),
+        out_shape=jax.ShapeDtypeStruct((Bp, Tp), jnp.float32),
         interpret=interpret,
-    )(x, feature, threshold, value)
+        name="forest_predict",
+    )(x, feature, threshold, leaf)
+    return per_tree[:B].sum(axis=1) / n_trees

@@ -1,64 +1,78 @@
-"""jit'd public wrapper for the forest-inference kernel.
+"""Public wrapper for the forest-inference kernel.
 
-Handles padding (batch to block_b, trees to block_t — padded trees carry
-value 0 everywhere and simply contribute nothing to the mean because we
-divide by the REAL tree count), feature-dim alignment, and the
-interpret-mode switch (interpret=True executes the kernel body with jnp on
-CPU; on a TPU runtime pass interpret=False).
+``pack_tables`` re-lays a dense forest (``core/forest_jax.DenseForest``,
+tree-major, level ``d`` at nodes [2^d-1, 2^{d+1}-1)) into the kernel's
+node-major tables with every level starting on an 8-row boundary, trees
+padded to a multiple of 128 lanes. Padded trees always go left and hold
+leaf value 0, so they add nothing to the sum; the kernel divides by the
+real tree count.
+
+``PallasForest`` packs once, keeps the tables on one device, and answers
+``(B, F) -> (B,)``. Whether the kernel runs compiled or interpreted follows
+that device's platform (``core.platform.pallas_interpret``).
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .kernel import forest_predict_kernel
+from ...core.platform import pallas_interpret
+from .kernel import (LANES, forest_predict_kernel, leaf_rows,
+                     level_offsets)
 
-_LANE = 8   # feature-dim padding multiple
+
+def pack_tables(feature, threshold, value, depth: int):
+    """(T, N) dense arrays -> (feature, threshold, leaf) kernel tables."""
+    feature = np.asarray(feature, dtype=np.int32)
+    threshold = np.asarray(threshold, dtype=np.float32)
+    value = np.asarray(value, dtype=np.float32)
+    T = feature.shape[0]
+    Tp = -(-T // LANES) * LANES
+    offs = level_offsets(depth)
+    feat = np.full((offs[-1], Tp), -1, dtype=np.int32)
+    thr = np.full((offs[-1], Tp), np.inf, dtype=np.float32)
+    for d in range(depth):
+        lo, w = 2 ** d - 1, 2 ** d
+        feat[offs[d]:offs[d] + w, :T] = feature[:, lo:lo + w].T
+        thr[offs[d]:offs[d] + w, :T] = threshold[:, lo:lo + w].T
+    w = 2 ** depth
+    leaf = np.zeros((leaf_rows(depth), Tp), dtype=np.float32)
+    leaf[:w, :T] = value[:, w - 1:2 * w - 1].T
+    return feat, thr, leaf
 
 
-def _pad_to(a, size: int, axis: int, fill=0):
-    pad = size - a.shape[axis]
-    if pad <= 0:
-        return a
-    widths = [(0, 0)] * a.ndim
-    widths[axis] = (0, pad)
-    return jnp.pad(a, widths, constant_values=fill)
+class PallasForest:
+    """A dense forest's kernel tables on one device, callable on (B, F)."""
+
+    def __init__(self, feature, threshold, value, depth: int, *,
+                 device=None, block_b: int = 64):
+        device = device if device is not None else jax.devices()[0]
+        self.tables = tuple(jax.device_put(t, device) for t in
+                            pack_tables(feature, threshold, value, depth))
+        self.static = dict(depth=int(depth), n_trees=int(len(feature)),
+                           block_b=block_b,
+                           interpret=pallas_interpret(device))
+
+    @classmethod
+    def from_dense(cls, dense, **kw) -> "PallasForest":
+        return cls(dense.feature, dense.threshold, dense.value, dense.depth,
+                   **kw)
+
+    def __call__(self, x) -> jax.Array:
+        return forest_predict_kernel(jnp.asarray(x, dtype=jnp.float32),
+                                     *self.tables, **self.static)
+
+    def lower(self, x):
+        """The jax ``Lowered`` of exactly what ``__call__(x)`` runs."""
+        return forest_predict_kernel.lower(
+            jnp.asarray(x, dtype=jnp.float32), *self.tables, **self.static)
 
 
 def forest_predict(x, feature, threshold, value, *, depth: int,
-                   block_b: int = 8, block_t: int = 32,
-                   interpret: bool = True):
-    """Predict with a DenseForest layout. Returns (B,) float32.
+                   block_b: int = 64):
+    """Predict with DenseForest arrays in one call. Returns (B,) float32.
 
-    x: (B, F). feature/threshold/value: (T, N) with N = 2^(depth+1)-1.
-    """
-    x = jnp.asarray(x, dtype=jnp.float32)
-    feature = jnp.asarray(feature, dtype=jnp.int32)
-    threshold = jnp.asarray(threshold, dtype=jnp.float32)
-    value = jnp.asarray(value, dtype=jnp.float32)
-    B, F = x.shape
-    T = feature.shape[0]
-
-    Fp = int(np.ceil(F / _LANE) * _LANE)
-    Bp = int(np.ceil(B / block_b) * block_b)
-    Tp = int(np.ceil(T / block_t) * block_t)
-
-    xp = _pad_to(_pad_to(x, Fp, 1), Bp, 0)
-    # padded trees: feature -1 (never matches the one-hot iota? it DOES need
-    # a valid path) -> use feature 0, threshold +inf (always left), value 0.
-    featp = _pad_to(feature, Tp, 0, fill=0)
-    thrp = _pad_to(threshold, Tp, 0, fill=np.float32(np.inf))
-    valp = _pad_to(value, Tp, 0, fill=0.0)
-
-    out = forest_predict_kernel(
-        xp, featp, thrp, valp, depth=depth, n_trees_total=T,
-        block_b=block_b, block_t=block_t, interpret=interpret)
-    return out[:B]
-
-
-def forest_predict_from_dense(dense, x, *, interpret: bool = True,
-                              block_b: int = 8, block_t: int = 32):
-    """Convenience over a ``repro.core.forest_jax.DenseForest``."""
-    return forest_predict(x, dense.feature, dense.threshold, dense.value,
-                          depth=dense.depth, block_b=block_b,
-                          block_t=block_t, interpret=interpret)
+    x: (B, F). feature/threshold/value: (T, N) with N = 2^(depth+1)-1."""
+    return PallasForest(feature, threshold, value, depth,
+                        block_b=block_b)(x)

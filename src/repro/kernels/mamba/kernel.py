@@ -69,8 +69,7 @@ def _ssd_kernel(x_ref, alog_ref, b_ref, c_ref, y_ref, hout_ref, h_scr, *,
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd_scan_kernel(x, alog, B, C, *, chunk: int = 128,
-                    interpret: bool = True):
+def ssd_scan_kernel(x, alog, B, C, *, chunk: int = 128, interpret: bool):
     """x: (Bsz, H, S, P); alog: (Bsz, H, S); B/C: (Bsz, S, N). S % chunk == 0
     (ops.py pads). Returns (y (Bsz, H, S, P), h_final (Bsz, H, N, P))."""
     Bsz, H, S, P = x.shape

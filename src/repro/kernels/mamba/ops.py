@@ -1,13 +1,15 @@
-"""jit'd public wrapper for the chunked SSD scan."""
+"""Public wrapper for the chunked SSD scan; the platform decides whether the
+kernel runs interpreted (``core.platform.pallas_interpret``)."""
 from __future__ import annotations
 
 import jax.numpy as jnp
 import numpy as np
 
+from ...core.platform import pallas_interpret
 from .kernel import ssd_scan_kernel
 
 
-def ssd_scan(x, alog, B, C, *, chunk: int = 128, interpret: bool = True):
+def ssd_scan(x, alog, B, C, *, chunk: int = 128):
     """x: (Bsz, S, H, P); alog: (Bsz, S, H); B/C: (Bsz, S, N).
     Returns (y (Bsz, S, H, P), h_final (Bsz, H, N, P)).
 
@@ -25,6 +27,7 @@ def ssd_scan(x, alog, B, C, *, chunk: int = 128, interpret: bool = True):
         C = jnp.pad(C, pad + [(0, 0)])
     xt = jnp.moveaxis(x, 2, 1)           # (Bsz, H, S, P)
     at = jnp.moveaxis(alog, 2, 1)        # (Bsz, H, S)
-    y, h = ssd_scan_kernel(xt, at, B, C, chunk=chunk, interpret=interpret)
+    y, h = ssd_scan_kernel(xt, at, B, C, chunk=chunk,
+                           interpret=pallas_interpret())
     y = jnp.moveaxis(y, 1, 2)[:, :S]
     return y, h

@@ -79,8 +79,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
         print(f"CELL {tag}")
         print(f"  lower {t_lower:.1f}s compile {t_compile:.1f}s")
         print(f"  memory_analysis: {mem}")
-        from ..core.hlo_analysis import xla_cost_analysis
-        ca = xla_cost_analysis(compiled)
+        ca = compiled.cost_analysis() or {}
         print(f"  cost_analysis: flops={ca.get('flops', 0):.3e} "
               f"bytes={ca.get('bytes accessed', 0):.3e}")
         print(f"  roofline: {rep.row()}")

@@ -17,19 +17,11 @@ collective overlap); they are no-ops on the CPU dry-run:
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-try:                                    # jax >= 0.5 explicit-sharding API
-    from jax.sharding import AxisType
-except ImportError:                     # older jax: meshes are Auto-typed
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
 def _make_mesh(shape, axes) -> Mesh:
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

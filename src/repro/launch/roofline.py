@@ -21,7 +21,7 @@ import json
 from dataclasses import asdict, dataclass
 
 from ..core.devices import ROOFLINE_HBM_BW, ROOFLINE_ICI_BW, ROOFLINE_PEAK_FLOPS
-from ..core.hlo_analysis import analyze_hlo_text, xla_cost_analysis
+from ..core.hlo_analysis import analyze_hlo_text
 
 HBM_PER_CHIP = 16 * 2**30      # v5e
 
@@ -134,7 +134,7 @@ def analyze_cell(compiled, *, arch: str, shape, mesh_name: str,
     txt = compiled.as_text()
     bf16 = getattr(cfg, "dtype", "") == "bfloat16"
     costs = analyze_hlo_text(txt, n_devices=n_devices, logical_bf16=bf16)
-    ca = xla_cost_analysis(compiled)
+    ca = compiled.cost_analysis() or {}
     mem = compiled.memory_analysis()
     arg_b = int(getattr(mem, "argument_size_in_bytes", 0))
     out_b = int(getattr(mem, "output_size_in_bytes", 0))

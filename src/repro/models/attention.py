@@ -16,8 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from ..sharding.context import constrain, current_ctx
-from .common import (EMBED, HEAD_DIM, HEADS, KV_HEADS, ParamSpec, apply_rope,
-                     opt_barrier)
+from .common import EMBED, HEAD_DIM, HEADS, KV_HEADS, ParamSpec, apply_rope
 
 
 def attn_specs(cfg) -> dict:
@@ -157,7 +156,7 @@ def attend_decode(cfg, p, x, cos, sin, cache, pos):
     # barrier: stops XLA:CPU from hoisting this layer's bf16->f32 dot-operand
     # convert across the WHOLE stacked cache (an f32 copy of every layer's
     # cache at once). TPU's MXU consumes bf16 natively — no convert at all.
-    k_cache, v_cache = opt_barrier(cache)
+    k_cache, v_cache = jax.lax.optimization_barrier(cache)
     q, k, v = _qkv(cfg, p, x)
     q = apply_rope(q, cos, sin)
     k = apply_rope(k, cos, sin)
@@ -168,7 +167,7 @@ def attend_decode(cfg, p, x, cos, sin, cache, pos):
     # second barrier: keep the RETURNED (bf16) cache distinct from the copy
     # the dot consumes, or XLA:CPU CSEs them and stacks the scan output in
     # f32 (2x cache memory). No-op on TPU.
-    return out, opt_barrier((k_cache, v_cache))
+    return out, jax.lax.optimization_barrier((k_cache, v_cache))
 
 
 def attend_cross(cfg, p, x, kv_cache):

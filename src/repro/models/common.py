@@ -87,33 +87,6 @@ def stack_specs(specs, n: int, axis_name: str = LAYERS):
         specs, is_leaf=lambda x: isinstance(x, ParamSpec))
 
 
-# ---------------------------------------------------------------- opt barrier
-
-@jax.custom_vjp
-def opt_barrier(tree):
-    """``jax.lax.optimization_barrier`` with a differentiation rule.
-
-    XLA's barrier op has no VJP registered (jax<=0.4.x raises
-    NotImplementedError under grad), but the barrier is purely a scheduling
-    fence: identity semantics, so cotangents pass through unchanged. The
-    forward pass keeps the real barrier (the fences in attention/lm exist to
-    stop XLA:CPU from hoisting dtype converts across the whole scanned
-    stack); the backward gets plain identity.
-    """
-    return jax.lax.optimization_barrier(tree)
-
-
-def _opt_barrier_fwd(tree):
-    return jax.lax.optimization_barrier(tree), None
-
-
-def _opt_barrier_bwd(_, cotangents):
-    return (cotangents,)
-
-
-opt_barrier.defvjp(_opt_barrier_fwd, _opt_barrier_bwd)
-
-
 # ------------------------------------------------------------------- numerics
 
 def rms_norm(x, w, eps: float = 1e-5):

@@ -22,7 +22,7 @@ from ..sharding.context import constrain, constrain_tree
 from .attention import (attend_decode, attend_prefill, attend_train,
                         attn_specs, kv_cache_shape)
 from .common import (BATCH, EMBED, KV_HEADS, HEAD_DIM, VOCAB, ParamSpec,
-                     cross_entropy_loss, mrope_cos_sin, opt_barrier, rms_norm,
+                     cross_entropy_loss, mrope_cos_sin, rms_norm,
                      rope_cos_sin, stack_specs)
 from .mlp import swiglu, swiglu_specs
 from .moe import moe_apply, moe_specs
@@ -99,7 +99,7 @@ def _run_blocks(cfg, params, x, cos, sin, mode, caches=None, pos=None):
         cast = jax.tree.map(
             lambda a: a.astype(act_dt)
             if jnp.issubdtype(a.dtype, jnp.floating) else a, tree)
-        return opt_barrier(cast)
+        return jax.lax.optimization_barrier(cast)
 
     def body(carry, xs):
         x = carry

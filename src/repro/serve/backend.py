@@ -32,6 +32,8 @@ import numpy as np
 from ..core.forest import ExtraTreesRegressor, predict_flat
 
 BACKENDS = ("tree-walk", "flat-numpy", "flat-jax", "dense-jax", "pallas")
+#: paths that embed the trees in a complete tree of at most ``dense_depth``
+TRUNCATING = ("dense-jax", "pallas")
 
 
 @runtime_checkable
@@ -109,6 +111,7 @@ def pad_pow2(fn: PredictorBackend) -> PredictorBackend:
             pad = np.broadcast_to(X[-1:], (Bp - B,) + X.shape[1:])
             X = np.concatenate([X, pad], axis=0)
         return np.asarray(fn(X))[:B]
+    wrapped.__wrapped__ = fn
     return wrapped
 
 
@@ -143,59 +146,54 @@ def build_transfer_engine(device, *, target: str = "time_us", monitor=None,
                              monitor=monitor, log_output=log_output)
 
 
+def max_depth(est: ExtraTreesRegressor) -> int:
+    return max((t.depth() for t in est.trees_), default=0)
+
+
+def exact_candidates(est: ExtraTreesRegressor, dense_depth: int,
+                     candidates=None) -> tuple[str, ...]:
+    """The paths auto-selection may choose from: ``candidates`` (default:
+    all), less the dense layouts when the trees are deeper than
+    ``dense_depth`` — those would serve the truncated forest."""
+    names = BACKENDS if candidates is None else tuple(candidates)
+    if max_depth(est) > dense_depth:
+        names = tuple(n for n in names if n not in TRUNCATING)
+    return names
+
+
 def build_backends(est: ExtraTreesRegressor, *, dense_depth: int = 10,
-                   only=None, pallas_interpret: bool = True,
-                   lenient: bool = False) -> dict[str, PredictorBackend]:
+                   only=None) -> dict[str, PredictorBackend]:
     """{name: fn(X float32 (B,F)) -> (B,) float64} for every requested path.
 
-    ``dense_depth`` caps the dense/pallas embedding depth; when the fitted
-    trees are shallower the actual max depth is used, making those paths
-    exact rather than truncated.
-
-    ``lenient=True`` (the auto-selection mode) skips paths that fail to
-    BUILD (e.g. a host without a working Pallas import) instead of raising;
-    an explicitly requested backend always raises.
+    ``only=None`` builds every path that is exact for this forest
+    (``exact_candidates``). A path named in ``only`` is built as asked:
+    ``dense-jax`` and ``pallas`` embed the trees at depth
+    ``min(dense_depth, max tree depth)`` and so replace deeper subtrees by
+    their mean. A path that fails to build raises.
     """
-    names = BACKENDS if only is None else tuple(only)
+    names = exact_candidates(est, dense_depth) if only is None else tuple(only)
     for n in names:
         if n not in BACKENDS:
             raise ValueError(f"unknown backend {n!r} (have {BACKENDS})")
     out: dict = {}
 
-    def attempt(build):
-        try:
-            build()
-        except Exception:
-            if not lenient:
-                raise
-
     if "tree-walk" in names:
         out["tree-walk"] = lambda X: est.predict(X)
 
     if "flat-numpy" in names or "flat-jax" in names:
-        def build_flat():
-            flat = est.to_flat()
-            if "flat-numpy" in names:
-                out["flat-numpy"] = lambda X: predict_flat(flat, X)
-            if "flat-jax" in names:
-                from ..core.forest_jax import FlatForestJax
-                out["flat-jax"] = pad_pow2(FlatForestJax(flat))
-        attempt(build_flat)
+        flat = est.to_flat()
+        if "flat-numpy" in names:
+            out["flat-numpy"] = lambda X: predict_flat(flat, X)
+        if "flat-jax" in names:
+            from ..core.forest_jax import FlatForestJax
+            out["flat-jax"] = pad_pow2(FlatForestJax(flat))
 
     if "dense-jax" in names or "pallas" in names:
-        def build_dense():
-            from ..core.forest_jax import DenseForestJax, to_dense
-            eff_depth = min(dense_depth,
-                            max((t.depth() for t in est.trees_), default=0))
-            dense = to_dense(est, depth=max(eff_depth, 1))
-            if "dense-jax" in names:
-                out["dense-jax"] = pad_pow2(DenseForestJax(dense))
-            if "pallas" in names:
-                def build_pallas():
-                    from ..kernels.forest.ops import forest_predict_from_dense
-                    out["pallas"] = pad_pow2(
-                        lambda X: forest_predict_from_dense(
-                            dense, X, interpret=pallas_interpret))
-                attempt(build_pallas)
-        attempt(build_dense)
+        from ..core.forest_jax import DenseForestJax, to_dense
+        dense = to_dense(est, depth=max(min(dense_depth, max_depth(est)), 1))
+        if "dense-jax" in names:
+            out["dense-jax"] = pad_pow2(DenseForestJax(dense))
+        if "pallas" in names:
+            from ..kernels.forest import PallasForest
+            out["pallas"] = pad_pow2(PallasForest.from_dense(dense))
     return out

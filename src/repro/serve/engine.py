@@ -15,8 +15,10 @@ the ``ForestEngine`` puts ONE serving API in front of all of them:
     model never changes — repeat queries from a scheduler loop are pure
     cache hits.
   * backend auto-selection: a short self-calibration pass
-    (``core/latency.py``) times every available path on a flush-sized batch
-    and picks the fastest for THIS host.
+    (``core/latency.py``) times every exact path on a flush-sized batch and
+    picks the fastest for THIS host. The dense layouts (``dense-jax``,
+    ``pallas``) are candidates only when no tree is deeper than
+    ``dense_depth``; a path that fails to build or to run raises.
   * hot-swap: ``engine.swap_estimator(new_est)`` atomically replaces the
     fitted forest without dropping in-flight or cached requests. Every
     answered batch is generation-uniform: all rows of one ``predict`` /
@@ -45,7 +47,7 @@ import numpy as np
 from ..core.forest import ExtraTreesRegressor
 from ..core.latency import calibrate_backends
 from .backend import (BACKENDS, PredictorBackend, build_backends,
-                      calibration_rows)
+                      calibration_rows, exact_candidates)
 
 __all__ = ["BACKENDS", "EngineConfig", "EngineStats", "ForestEngine",
            "MultiDeviceEngine", "build_backends"]
@@ -61,7 +63,6 @@ class EngineConfig:
     max_batch: int = 64            # flush when this many singles are pending
     max_delay_ms: float = 2.0      # ... or when the oldest single is this old
     cache_size: int = 4096         # LRU entries; 0 disables caching
-    pallas_interpret: bool = True
     calibration_iters: int = 3
 
 
@@ -133,13 +134,11 @@ class ForestEngine:
         this single hook (``ShardedForestEngine`` returns its partitioned
         path) — both __init__ and swap_estimator route through it."""
         cfg = self.config
-        only = cfg.backends
         if cfg.backend != "auto":
             only = (cfg.backend,)
-        return build_backends(
-            est, dense_depth=cfg.dense_depth, only=only,
-            pallas_interpret=cfg.pallas_interpret,
-            lenient=cfg.backend == "auto")
+        else:
+            only = exact_candidates(est, cfg.dense_depth, cfg.backends)
+        return build_backends(est, dense_depth=cfg.dense_depth, only=only)
 
     def _select(self, backends: dict[str, PredictorBackend],
                 calibration_X) -> str:
@@ -153,10 +152,13 @@ class ForestEngine:
         xb = np.ascontiguousarray(calibration_X, dtype=np.float32)
         self.calibration = calibrate_backends(
             backends, xb, iters=cfg.calibration_iters)
-        best = min(self.calibration, key=self.calibration.get)
-        if not np.isfinite(self.calibration[best]):
-            raise RuntimeError(f"no usable backend: {self.calibration}")
-        return best
+        return min(self.calibration, key=self.calibration.get)
+
+    @property
+    def predictor(self) -> PredictorBackend:
+        """The installed backend callable; a ``pad_pow2`` wrapper exposes
+        the path it wraps (e.g. a ``FlatForestJax``) as ``__wrapped__``."""
+        return self._predict_fn
 
     # -------------------------------------------------------------- hot-swap
 

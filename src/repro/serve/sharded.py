@@ -11,8 +11,8 @@ Two placements, picked automatically:
 
   * ``mesh`` — with >= n_shards JAX devices, the dense arrays are laid out
     with ``jax.sharding`` (1-D mesh over the tree axis) and one jitted
-    ``shard_map`` call traverses every shard in parallel, combining partials
-    with ``lax.psum`` across the mesh. This is the TPU-pod path.
+    ``jax.shard_map`` call traverses every shard in parallel, combining
+    partials with ``lax.psum`` across the mesh. This is the TPU-pod path.
   * ``loop`` — otherwise (e.g. this CPU container, or forced shard counts
     for testing) each shard's block is placed round-robin over the available
     devices and dispatched as its own async jit / Pallas call; XLA overlaps
@@ -44,7 +44,7 @@ __all__ = ["ShardedForestEngine", "ShardedForestPredictor"]
 
 
 @partial(jax.jit, static_argnames=("depth",))
-def _leaf_sum_jit(feature, threshold, value, x, depth: int):
+def _leaf_sum_jit(feature, threshold, value, x, *, depth: int):
     return dense_leaf_sum(feature, threshold, value, x, depth)
 
 
@@ -68,7 +68,7 @@ class ShardedForestPredictor:
 
     def __init__(self, est: ExtraTreesRegressor, *, n_shards: int,
                  dense_depth: int = 10, use_pallas: bool = False,
-                 pallas_interpret: bool = True, force_loop: bool = False):
+                 force_loop: bool = False):
         if not est.trees_:
             raise ValueError("estimator is not fitted")
         n_trees = len(est.trees_)
@@ -82,7 +82,6 @@ class ShardedForestPredictor:
         self.n_shards = n_shards
         self.depth = dense.depth
         self.use_pallas = use_pallas
-        self.pallas_interpret = pallas_interpret
         self.devices = jax.devices()
         self.bounds = _shard_bounds(n_trees, n_shards)
         self.shard_sizes = [b - a for a, b in self.bounds]
@@ -103,6 +102,15 @@ class ShardedForestPredictor:
         kind = "pallas" if self.use_pallas else "dense"
         base = f"sharded-{kind}-{self.placement}x{self.n_shards}"
         return f"{base}-deg{len(self.dead)}" if self.dead else base
+
+    @property
+    def shard_devices(self) -> list:
+        """The device holding each live shard's trees, in shard order."""
+        if self.placement == "mesh":
+            shards = sorted(self._arrays[0].addressable_shards,
+                            key=lambda s: s.index[0].start)
+            return [s.device for s in shards]
+        return [dev for _, dev, _ in self._shards]
 
     # --------------------------------------------------------- shard failure
 
@@ -132,7 +140,6 @@ class ShardedForestPredictor:
         p.n_shards = self.n_shards
         p.depth = self.depth
         p.use_pallas = self.use_pallas
-        p.pallas_interpret = self.pallas_interpret
         p.devices = self.devices
         p.bounds = self.bounds
         p.shard_sizes = [b - a for i, (a, b) in enumerate(self.bounds)
@@ -148,7 +155,6 @@ class ShardedForestPredictor:
     # -------------------------------------------------------------- mesh path
 
     def _build_mesh(self, dense: DenseForest) -> None:
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
         # equal-size shards for the mesh: pad to S * ceil(T/S) inert trees,
@@ -173,12 +179,13 @@ class ShardedForestPredictor:
         def per_shard(x, f, t, v):
             # each device traverses its (ts, N) block; psum combines the
             # partial leaf sums across the tree mesh
-            return jax.lax.psum(dense_leaf_sum(f, t, v, x, depth), "trees")
+            return jax.lax.psum(
+                dense_leaf_sum(f, t, v, x, depth, axis_name="trees"), "trees")
 
-        fn = shard_map(per_shard, mesh,
-                       in_specs=(P(), P("trees", None), P("trees", None),
-                                 P("trees", None)),
-                       out_specs=P())
+        fn = jax.shard_map(per_shard, mesh=mesh,
+                           in_specs=(P(), P("trees", None), P("trees", None),
+                                     P("trees", None)),
+                           out_specs=P())
         self._mesh_fn = jax.jit(lambda x, f, t, v: fn(x, f, t, v) / n_trees)
 
     # -------------------------------------------------------------- loop path
@@ -187,18 +194,22 @@ class ShardedForestPredictor:
         # round-robin shard blocks over whatever devices exist; jit dispatch
         # is async, so per-device work overlaps even though Python drives
         # the loop
+        from ..kernels.forest import PallasForest
+
         self._shards = []
         for i, (a, b) in enumerate(self.bounds):
             if i in self.dead:
                 continue
             dev = self.devices[i % len(self.devices)]
-            arrays = tuple(jax.device_put(np.ascontiguousarray(arr[a:b]), dev)
-                           for arr in (dense.feature, dense.threshold,
-                                       dense.value))
-            self._shards.append((arrays, dev, b - a))
-        if self.use_pallas:
-            from ..kernels.forest.ops import forest_predict
-            self._pallas_predict = forest_predict
+            block = (dense.feature[a:b], dense.threshold[a:b],
+                     dense.value[a:b])
+            if self.use_pallas:
+                run = PallasForest(*block, self.depth, device=dev)
+            else:
+                arrays = tuple(jax.device_put(np.ascontiguousarray(arr), dev)
+                               for arr in block)
+                run = partial(_leaf_sum_jit, *arrays, depth=self.depth)
+            self._shards.append((run, dev, b - a))
 
     def _loop_call(self, x: jax.Array) -> np.ndarray:
         # one input transfer per unique device, not per shard
@@ -206,17 +217,10 @@ class ShardedForestPredictor:
         for _, dev, _ in self._shards:
             if dev not in x_on:
                 x_on[dev] = jax.device_put(x, dev)
-        partials = []
-        for (f, t, v), dev, size in self._shards:
-            xs = x_on[dev]
-            if self.use_pallas:
-                # the Pallas kernel returns the shard MEAN (it divides by its
-                # real tree count); rescale to a partial sum
-                partials.append((self._pallas_predict(
-                    xs, f, t, v, depth=self.depth,
-                    interpret=self.pallas_interpret), size))
-            else:
-                partials.append((_leaf_sum_jit(f, t, v, xs, self.depth), 1))
+        # the Pallas kernel returns the shard MEAN (it divides by its real
+        # tree count): rescale to a partial sum
+        partials = [(run(x_on[dev]), size if self.use_pallas else 1)
+                    for run, dev, size in self._shards]
         total = np.zeros(x.shape[0], dtype=np.float64)
         for part, scale in partials:       # collect AFTER all dispatches
             total += np.asarray(part, dtype=np.float64) * scale
@@ -264,17 +268,14 @@ class ShardedForestEngine(ForestEngine):
             est, n_shards=self.n_shards,
             dense_depth=self.config.dense_depth,
             use_pallas=self.use_pallas,
-            pallas_interpret=self.config.pallas_interpret,
             force_loop=self.force_loop)
-        fn = pad_pow2(predictor)
-        fn.predictor = predictor
-        return {predictor.name: fn}
+        return {predictor.name: pad_pow2(predictor)}
 
     # placement metadata reflects the INSTALLED predictor (committed under
     # the engine lock), never one mid-build or from a failed swap
     @property
     def _installed(self) -> ShardedForestPredictor:
-        return self._predict_fn.predictor
+        return self.predictor.__wrapped__
 
     @property
     def placement(self) -> str:
@@ -283,6 +284,10 @@ class ShardedForestEngine(ForestEngine):
     @property
     def shard_sizes(self) -> list[int]:
         return self._installed.shard_sizes
+
+    @property
+    def shard_devices(self) -> list:
+        return self._installed.shard_devices
 
     @property
     def dead_shards(self) -> frozenset[int]:
@@ -321,7 +326,6 @@ class ShardedForestEngine(ForestEngine):
             base = self._installed
             degraded = base.without_shard(idx)
             fn = pad_pow2(degraded)
-            fn.predictor = degraded
             with self._cond:
                 if self._closed:
                     raise RuntimeError("engine is closed")

@@ -2,8 +2,10 @@
 
 Per workload:
   * features from the lowered StableHLO (recorded ONCE — portability),
-  * ``cpu-host``: REAL wall-clock, repeated ``repeats`` times, median kept,
-    CoV recorded (paper Fig. 3),
+  * REAL wall-clock on JAX's default device, repeated ``repeats`` times,
+    median kept, CoV recorded (paper Fig. 3). It is filed under
+    ``measured_device()``: ``cpu-host`` on a CPU, the device's
+    ``device_kind`` (e.g. ``TPU v5 lite``) anywhere else,
   * each simulated TPU device model: analytic time (median-of-10 noisy
     draws) + power (mean-of-10) — the SIMULATED GATE, DESIGN.md §6.
 
@@ -12,6 +14,7 @@ Returns a ``repro.core.dataset.Dataset``; cached as JSON under artifacts/.
 from __future__ import annotations
 
 import time
+from collections.abc import Collection
 from pathlib import Path
 
 import jax
@@ -27,7 +30,13 @@ from .suite import Workload, suite
 ARTIFACT = Path(__file__).resolve().parents[3] / "artifacts" / "suite_dataset.json"
 
 
-def _measure_cpu(fn, args, repeats: int) -> tuple[float, float]:
+def measured_device() -> str:
+    """The name real timings are filed under: follows the platform."""
+    dev = jax.devices()[0]
+    return CPU_HOST.name if dev.platform == "cpu" else dev.device_kind
+
+
+def _measure(fn, args, repeats: int) -> tuple[float, float]:
     jitted = jax.jit(fn)
     out = jitted(*args)
     jax.block_until_ready(out)
@@ -53,19 +62,20 @@ def spec_from_features(fv, work_items: float, n_shards: int = 1) -> WorkloadSpec
 
 
 def measure_workload(w: Workload, rng, repeats: int = 10,
-                     measure_cpu: bool = True):
+                     measure: bool = True):
     """Features (extracted ONCE from the portable IR) + per-device targets
     for ONE workload. Shared by the batch collector below and the streaming
     collector (``workloads/stream.py``): given the same rng state it yields
     identical measurements on the simulated devices, which is what makes
     streamed and batch-collected datasets byte-identical under one seed.
-    Returns (FeatureVector, targets dict)."""
+    ``measure=False`` skips the wall-clock timing (features and simulated
+    targets only). Returns (FeatureVector, targets dict)."""
     lowered = jax.jit(w.fn).lower(*w.args)
     fv = extract_from_lowered(lowered, LaunchConfig(work_items=w.work_items))
     targets = {}
-    if measure_cpu:
-        t_us, cov = _measure_cpu(w.fn, w.args, repeats)
-        targets[CPU_HOST.name] = {"time_us": t_us, "time_cov": cov}
+    if measure:
+        t_us, cov = _measure(w.fn, w.args, repeats)
+        targets[measured_device()] = {"time_us": t_us, "time_cov": cov}
     spec = spec_from_features(fv, w.work_items)
     for dev in SIMULATED_DEVICES:
         t_us, tcov = simulate_time_median_us(spec, dev, rng, repeats)
@@ -76,13 +86,16 @@ def measure_workload(w: Workload, rng, repeats: int = 10,
 
 
 def collect(workloads: list[Workload] | None = None, repeats: int = 10,
-            measure_cpu: bool = True, seed: int = 0,
+            measure: bool | Collection[int] = True, seed: int = 0,
             progress=None) -> Dataset:
+    """``measure``: time every workload (True), none (False), or only the
+    workloads at these indices."""
     workloads = workloads if workloads is not None else suite()
     ds = Dataset()
     rng = np.random.default_rng(seed)
     for i, w in enumerate(workloads):
-        fv, targets = measure_workload(w, rng, repeats, measure_cpu)
+        timed = measure if isinstance(measure, bool) else i in measure
+        fv, targets = measure_workload(w, rng, repeats, timed)
         ds.add(w.app, w.kernel, w.variant, fv, targets)
         if progress and (i + 1) % 20 == 0:
             progress(f"  collected {i+1}/{len(workloads)}")

@@ -34,13 +34,13 @@ __all__ = ["iter_samples", "StreamingCollector"]
 
 
 def iter_samples(workloads: list[Workload] | None = None, *,
-                 repeats: int = 10, measure_cpu: bool = True,
+                 repeats: int = 10, measure: bool = True,
                  seed: int = 0) -> Iterator[Sample]:
     """Measure workloads one at a time, yielding each finished Sample."""
     workloads = workloads if workloads is not None else suite()
     rng = np.random.default_rng(seed)
     for w in workloads:
-        fv, targets = measure_workload(w, rng, repeats, measure_cpu)
+        fv, targets = measure_workload(w, rng, repeats, measure)
         yield Sample.from_feature_vector(w.app, w.kernel, w.variant, fv,
                                          targets)
 
@@ -62,7 +62,7 @@ class StreamingCollector:
 
     def __init__(self, store: DatasetStore,
                  workloads: list[Workload] | None = None, *,
-                 repeats: int = 10, measure_cpu: bool = False, seed: int = 0,
+                 repeats: int = 10, measure: bool = False, seed: int = 0,
                  chunk_size: int = 1, throttle_s: float = 0.0,
                  on_chunk: Callable[[int, int], None] | None = None):
         if chunk_size < 1:
@@ -70,7 +70,7 @@ class StreamingCollector:
         self.store = store
         self.workloads = workloads if workloads is not None else suite()
         self.repeats = repeats
-        self.measure_cpu = measure_cpu
+        self.measure = measure
         self.seed = seed
         self.chunk_size = chunk_size
         self.throttle_s = throttle_s
@@ -141,7 +141,7 @@ class StreamingCollector:
         buf: list[Sample] = []
         try:
             for s in iter_samples(self.workloads, repeats=self.repeats,
-                                  measure_cpu=self.measure_cpu,
+                                  measure=self.measure,
                                   seed=self.seed):
                 if self._stop.is_set():
                     break

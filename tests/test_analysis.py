@@ -7,7 +7,7 @@ import pytest
 from repro.core.dataset import Dataset
 from repro.core.devices import DEVICE_MODELS, EDGE_DVFS, TPU_V5E
 from repro.core.features import FEATURE_NAMES, LaunchConfig, extract
-from repro.core.hlo_analysis import analyze_hlo_text, xla_cost_analysis
+from repro.core.hlo_analysis import analyze_hlo_text
 from repro.core.power import simulate_power_w
 from repro.core.scheduler import DevicePredictor, schedule, speedup_vs_baseline
 from repro.core.simulate import WorkloadSpec, simulate_time_us
@@ -32,7 +32,7 @@ def test_hlo_flops_trip_weighted():
     assert costs.flops == pytest.approx(expect, rel=0.2)
     assert costs.while_trips and costs.while_trips[0] == L
     # XLA's own cost_analysis counts the body ONCE — our analyzer corrects it
-    xla = xla_cost_analysis(compiled)["flops"]
+    xla = compiled.cost_analysis()["flops"]
     assert costs.flops > 2 * xla
 
 

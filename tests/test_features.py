@@ -83,13 +83,12 @@ def test_vector_matches_names():
 
 def test_collectives_counted_as_sync():
     import jax
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
     mesh = Mesh(np.asarray(jax.devices()[:1]).reshape(1), ("d",))
 
     def f(x):
-        return shard_map(lambda v: jax.lax.psum(v, "d"), mesh=mesh,
-                         in_specs=P("d"), out_specs=P())(x)
+        return jax.shard_map(lambda v: jax.lax.psum(v, "d"), mesh=mesh,
+                             in_specs=P("d"), out_specs=P())(x)
 
     fv = extract(f, jax.ShapeDtypeStruct((8,), jnp.float32))
     assert fv["sync_ops"] >= 1

@@ -31,7 +31,7 @@ def test_forest_kernel_vs_ref(fitted, depth, batch):
                              jnp.asarray(dense.threshold),
                              jnp.asarray(dense.value), depth=depth)
     out = forest_predict(X, dense.feature, dense.threshold, dense.value,
-                         depth=depth, block_b=8, block_t=8)
+                         depth=depth, block_b=8)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-6)
 
@@ -53,7 +53,7 @@ def test_forest_deep_dense_approaches_exact(fitted):
     exact = fitted.predict(X)
     deep = to_dense(fitted, depth=14)
     out = np.asarray(forest_predict(X, deep.feature, deep.threshold,
-                                    deep.value, depth=14, block_t=8))
+                                    deep.value, depth=14))
     assert np.abs(out - exact).max() < 0.05        # truncation error bound
 
 

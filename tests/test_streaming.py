@@ -42,8 +42,8 @@ def _sample(i: int, kernel: str = "k") -> Sample:
 
 def test_streamed_samples_equal_batch_collect():
     wls = _workloads()
-    streamed = list(iter_samples(wls, repeats=3, measure_cpu=False, seed=7))
-    batch = collect(wls, repeats=3, measure_cpu=False, seed=7)
+    streamed = list(iter_samples(wls, repeats=3, measure=False, seed=7))
+    batch = collect(wls, repeats=3, measure=False, seed=7)
     assert len(streamed) == len(batch.samples)
     for a, b in zip(streamed, batch.samples):
         assert a.to_json() == b.to_json()
@@ -54,7 +54,7 @@ def test_streaming_collector_snapshot_determinism():
     snaps = []
     for chunk in (1, 3):                       # chunking must not matter
         store = DatasetStore(max_per_group=100, seed=0)
-        c = StreamingCollector(store, wls, repeats=3, measure_cpu=False,
+        c = StreamingCollector(store, wls, repeats=3, measure=False,
                                seed=11, chunk_size=chunk)
         assert c.run_sync() == len(wls)
         snaps.append(store.snapshot())
@@ -67,7 +67,7 @@ def test_streaming_collector_background_thread():
     wls = _workloads()
     store = DatasetStore(max_per_group=100, seed=0)
     chunks = []
-    c = StreamingCollector(store, wls, repeats=2, measure_cpu=False, seed=0,
+    c = StreamingCollector(store, wls, repeats=2, measure=False, seed=0,
                            chunk_size=2,
                            on_chunk=lambda v, n: chunks.append((v, n)))
     with c:
@@ -197,12 +197,12 @@ def test_refresher_blacklists_failing_version():
 def test_refresher_background_thread_and_fit_fn_helper():
     wls = _workloads(4)
     store = DatasetStore(max_per_group=100, seed=0)
-    store.extend(list(iter_samples(wls[:2], repeats=2, measure_cpu=False,
+    store.extend(list(iter_samples(wls[:2], repeats=2, measure=False,
                                    seed=0)))
     fit = single_device_fit_fn("tpu-v5e", n_estimators=8)
     eng = ForestEngine(fit(store.snapshot().dataset), backend="flat-numpy")
     with EngineRefresher(store, eng, fit, min_samples=1, poll_s=0.01) as ref:
-        store.extend(list(iter_samples(wls[2:], repeats=2, measure_cpu=False,
+        store.extend(list(iter_samples(wls[2:], repeats=2, measure=False,
                                        seed=1)))
         deadline = time.monotonic() + 30
         while ref.stats.last_version < store.version:

@@ -293,7 +293,7 @@ def test_ingest_store_streams_probes():
     workloads = suite(sizes=("s",))[:3]
     tp = TransferPredictor(TPU_V5E)
     coll = StreamingCollector(
-        store, workloads, repeats=2, measure_cpu=False, seed=0,
+        store, workloads, repeats=2, measure=False, seed=0,
         on_chunk=lambda _v, _n: tp.ingest_store(store))
     n = coll.run_sync()
     assert n == 3

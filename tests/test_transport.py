@@ -7,9 +7,11 @@ import json
 import socket
 import struct
 import subprocess
+import sys
 import threading
 import time
 import zlib
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -715,3 +717,28 @@ def test_mixed_pool_survives_server_kill_and_restart(fitted):
             frontend.close()                   # closes pool + both replicas
         proc.kill()
         proc.wait(timeout=10)
+
+
+# ------------------------------------------- one process per device
+
+_SMOKE_PARENT = """
+import sys
+sys.path.insert(0, {src!r})
+from jax._src import xla_bridge
+from repro.cluster.remote import main
+rc = main([{flag!r}])
+print("RC", rc, "BACKEND_INITIALIZED", xla_bridge.backends_are_initialized())
+"""
+
+
+@pytest.mark.parametrize("flag", ["--selftest", "--obs-smoke"])
+def test_smoke_parent_leaves_the_device_to_its_server(flag):
+    """The smoke CLIs spawn a server child that serves from JAX's default
+    device. A chip belongs to one process, so the parent that checks the
+    child's answers must never initialize a JAX backend itself."""
+    src = str(Path(__file__).resolve().parents[1] / "src")
+    out = subprocess.run(
+        [sys.executable, "-c", _SMOKE_PARENT.format(src=src, flag=flag)],
+        capture_output=True, text=True, timeout=240)
+    assert out.returncode == 0, out.stderr
+    assert "RC 0 BACKEND_INITIALIZED False" in out.stdout, out.stdout

@@ -10,8 +10,10 @@ import os
 import subprocess
 import sys
 from pathlib import Path
+from types import SimpleNamespace
 
 import numpy as np
+import pytest
 
 from repro.workloads.suite import _workload_seed, suite
 
@@ -133,3 +135,28 @@ def test_feature_coverage_scores_spread_above_concentration():
     ref = spread
     assert (feature_coverage(spread, ref=ref)["score"]
             > feature_coverage(clump, ref=ref)["score"])
+
+
+# ------------------------------------------------- where timings are filed
+
+@pytest.mark.parametrize("platform,kind,label", [
+    ("cpu", "cpu", "cpu-host"),
+    ("tpu", "TPU v5 lite", "TPU v5 lite"),
+])
+def test_measured_device_label_follows_platform(monkeypatch, platform, kind,
+                                                label):
+    import repro.workloads.collect as collect_mod
+    dev = SimpleNamespace(platform=platform, device_kind=kind)
+    monkeypatch.setattr(collect_mod.jax, "devices", lambda *a: [dev])
+    assert collect_mod.measured_device() == label
+
+
+def test_collect_on_cpu_files_timings_under_cpu_host():
+    from repro.workloads.collect import collect
+    ds = collect(suite(sizes=("s",))[:3], repeats=2, measure={0, 2})
+    assert ["cpu-host" in s.targets for s in ds.samples] == [True, False,
+                                                              True]
+    assert all(s.targets["cpu-host"]["time_us"] > 0
+               for s in ds.samples if "cpu-host" in s.targets)
+    # the simulated devices' targets come with every workload either way
+    assert all("tpu-v5e" in s.targets for s in ds.samples)
