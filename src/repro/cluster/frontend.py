@@ -53,7 +53,7 @@ from dataclasses import dataclass, field, replace
 import numpy as np
 
 from ..core.scheduler import slack_priority
-from ..obs import Observability, Reservoir, Span, TraceContext
+from ..obs import Observability, Reservoir, Span, TraceContext, span
 from .replicas import ReplicaPool
 
 __all__ = ["ClusterFrontend", "DeadlineExceeded", "FrontendConfig",
@@ -101,6 +101,8 @@ class FrontendStats:
     retries: int = 0               # failovers to another replica
     deadlines_forwarded: int = 0   # dispatches carrying a member deadline
     schedules: int = 0             # DVFS schedule() calls answered
+    wait_s: float = 0.0            # seconds dispatched requests queued
+    waited: int = 0                # requests those seconds are summed over
     by_replica: dict = field(default_factory=dict)  # name -> rows served
     # tenant -> {"submitted": rows, "rejected": count, "served": rows}
     by_tenant: dict = field(default_factory=dict)
@@ -181,10 +183,13 @@ class ClusterFrontend:
         reg = obs.registry
         for name in ("submitted", "rejected", "quota_rejected", "cancelled",
                      "expired", "served", "failed", "dispatches", "retries",
-                     "deadlines_forwarded", "schedules"):
+                     "deadlines_forwarded", "schedules", "waited"):
             reg.register_fn(f"frontend.{name}",
                             lambda n=name: getattr(self.stats, n),
                             kind="counter")
+        # ``frontend.wait_s`` is the live histogram below
+        reg.register_fn("frontend.wait_s_total",
+                        lambda: self.stats.wait_s, kind="counter")
         reg.register_fn("frontend.queue_depth", self.queue_len)
         reg.register_fn("frontend.queued_rows", lambda: self._queued_rows)
         reg.register_fn("frontend.healthy_replicas",
@@ -236,18 +241,21 @@ class ClusterFrontend:
         to cancel. A batch of more than ``max_queue`` rows can never be
         admitted; split it client-side.
         """
-        X = np.ascontiguousarray(X, dtype=np.float32)
-        if X.ndim != 2:
-            raise ValueError(f"expected (B, F) batch, got shape {X.shape}")
-        if self.n_features is not None and X.shape[1] != self.n_features:
-            raise ValueError(f"expected {self.n_features} features, "
-                             f"got {X.shape[1]}")
-        if X.shape[0] == 0:                      # nothing to queue
-            fut: Future = Future()
-            fut.set_result(np.empty(0, dtype=np.float64))
-            return fut
-        return self._enqueue(X, X.shape[0], priority, deadline_s, tenant,
-                             trace_ctx)
+        with span("frontend.admit") as admit:
+            X = np.ascontiguousarray(X, dtype=np.float32)
+            if X.ndim != 2:
+                raise ValueError(
+                    f"expected (B, F) batch, got shape {X.shape}")
+            if self.n_features is not None and X.shape[1] != self.n_features:
+                raise ValueError(f"expected {self.n_features} features, "
+                                 f"got {X.shape[1]}")
+            admit.set_metadata(rows=X.shape[0])
+            if X.shape[0] == 0:                  # nothing to queue
+                fut: Future = Future()
+                fut.set_result(np.empty(0, dtype=np.float64))
+                return fut
+            return self._enqueue(X, X.shape[0], priority, deadline_s, tenant,
+                                 trace_ctx)
 
     def _enqueue(self, x: np.ndarray, rows: int, priority: int | None,
                  deadline_s: float | None, tenant: str | None,
@@ -417,6 +425,10 @@ class ClusterFrontend:
                     self._cond.wait(timeout=0.05)
                 if self._closed:
                     return
+                # the pop, expiry checks and hand-off: closed below, after
+                # the executor took the batch
+                pop = span("frontend.pop")
+                pop.__enter__()
                 batch = []
                 for _ in range(min(len(self._queue),
                                    self.config.dispatch_batch)):
@@ -441,6 +453,8 @@ class ClusterFrontend:
                     else:
                         wait = now - req.t_submit
                         self._waits_s.offer(wait)
+                        self.stats.wait_s += wait
+                        self.stats.waited += 1
                         if self._wait_hist is not None:
                             self._wait_hist.observe(wait)
                         if req.queue_span is not None:
@@ -459,10 +473,13 @@ class ClusterFrontend:
                     f"before dispatch"))
             if live:
                 self._executor.submit(self._dispatch, live)
+            pop.set_metadata(rows=sum(r.rows for r in batch))
+            pop.__exit__(None, None, None)
 
     def _dispatch(self, reqs: list[_Request]) -> None:
         try:
-            self._dispatch_inner(reqs)
+            with span("frontend.dispatch", rows=sum(r.rows for r in reqs)):
+                self._dispatch_inner(reqs)
         finally:
             with self._cond:
                 self._dispatching -= 1
@@ -476,8 +493,9 @@ class ClusterFrontend:
     def _stack(reqs: list[_Request]) -> np.ndarray:
         """Rows + batches -> one (N, F) engine call (batch entries keep
         their block contiguous, so results split back by row counts)."""
-        return np.concatenate([r.x[None, :] if r.x.ndim == 1 else r.x
-                               for r in reqs])
+        with span("frontend.stack", rows=sum(r.rows for r in reqs)):
+            return np.concatenate([r.x[None, :] if r.x.ndim == 1 else r.x
+                                   for r in reqs])
 
     def _dispatch_inner(self, reqs: list[_Request]) -> None:
         X = self._stack(reqs)

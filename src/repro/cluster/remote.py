@@ -78,7 +78,8 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
-from ..obs import Observability, TraceContext, ctx_from_meta, ctx_to_meta
+from ..obs import (Observability, TraceContext, ctx_from_meta, ctx_to_meta,
+                   span)
 from .frontend import ClusterFrontend
 from .transport import (PROTOCOL_V3, PROTOCOL_VERSION, AuthError,
                         ProtocolError, TransportError, decode_error,
@@ -488,10 +489,12 @@ class PredictionServer:
 
         Synchronous failures (bad payload, rejection at admission) raise
         back into ``_handle`` and go out as an inline error reply."""
-        X = self._peer_array(frame, payload)
-        budget_s = self._peer_deadline_s(frame)
-        priority = self._peer_priority(frame)
-        ctx = self._peer_trace(frame)
+        with span("wire.decode") as decode:
+            X = self._peer_array(frame, payload)
+            decode.set_metadata(rows=len(X))
+            budget_s = self._peer_deadline_s(frame)
+            priority = self._peer_priority(frame)
+            ctx = self._peer_trace(frame)
         rid = frame.get("id")
         fut = self.frontend.submit_batch(X, priority=priority,
                                          deadline_s=budget_s,
@@ -518,12 +521,13 @@ class PredictionServer:
                             "error": encode_error(exc),
                             **self._reply_spans(ctx, t0, {})})
                 return
-            desc, pl = pack_array(y)
-            self.requests_served += 1
-            self._respond_state(
-                state, {"v": PROTOCOL_V3, "id": rid, "ok": True,
-                        "array": desc,
-                        **self._reply_spans(ctx, t0, {})}, pl)
+            with span("wire.encode", rows=len(y)):
+                desc, pl = pack_array(y)
+                self.requests_served += 1
+                self._respond_state(
+                    state, {"v": PROTOCOL_V3, "id": rid, "ok": True,
+                            "array": desc,
+                            **self._reply_spans(ctx, t0, {})}, pl)
         finally:
             with self._lock:
                 self._in_flight -= 1

@@ -7,7 +7,9 @@ Three pillars, one bundle:
   snapshot (``op="metrics"``) or Prometheus text.
 * :class:`Tracer` — distributed request tracing; trace context rides the
   existing v2/v3 frame meta (no protocol bump), server spans ship back in
-  the reply so the client reconstructs the full cross-process tree.
+  the reply so the client reconstructs the full cross-process tree;
+  :func:`span` writes the same stages as host spans into the JAX
+  profiler's trace while a profiler session runs.
 * :class:`CalibrationMonitor` — live per-(device, target) MAPE with a
   drift signal ``EngineRefresher`` polls to trigger refits.
 
@@ -36,6 +38,7 @@ from .tracing import (
     ctx_to_meta,
     new_span_id,
     new_trace_id,
+    span,
 )
 
 __all__ = [
@@ -43,7 +46,7 @@ __all__ = [
     "MetricsRegistry", "Counter", "Gauge", "Histogram", "Reservoir",
     "Ewma", "DEFAULT_LATENCY_BUCKETS_S",
     "Tracer", "Span", "TraceContext", "ctx_to_meta", "ctx_from_meta",
-    "new_trace_id", "new_span_id",
+    "new_trace_id", "new_span_id", "span",
     "CalibrationMonitor",
 ]
 

@@ -24,6 +24,14 @@ Span stages across a remote predict::
 A slow-request sampler logs a structured one-line JSON span dump for any
 root span slower than ``slow_threshold_s`` (bounded ring of recent dumps
 kept for ``--stats``/examples).
+
+``span(name, **stats)`` is the other half: a host span in the JAX
+profiler's own trace, on the clock of the device planes, so a trace can
+say what the host was doing while the chip waited. Names are
+``<layer>.<stage>`` in the same stage vocabulary (``wire.decode``,
+``frontend.dispatch``, ``engine.lookup``, ``backend.wait``, ...). Spans
+exist only while a profiler session runs; otherwise ``span`` returns one
+shared no-op object.
 """
 from __future__ import annotations
 
@@ -34,8 +42,9 @@ import threading
 import time
 from collections import OrderedDict
 from dataclasses import dataclass, field
+from functools import cache
 
-__all__ = ["Span", "TraceContext", "Tracer",
+__all__ = ["Span", "TraceContext", "Tracer", "span",
            "new_trace_id", "new_span_id", "ctx_to_meta", "ctx_from_meta"]
 
 log = logging.getLogger("repro.obs.trace")
@@ -47,6 +56,43 @@ def new_trace_id() -> str:
 
 def new_span_id() -> str:
     return os.urandom(4).hex()
+
+
+class _Off:
+    """The span while no profiler session runs: enters, exits and takes
+    stats at no cost."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> "_Off":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        return None
+
+    def set_metadata(self, **stats) -> None:
+        return None
+
+
+_OFF = _Off()
+
+
+@cache
+def _annotation():
+    # imported on first use: importing ``repro.obs`` alone loads no JAX
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation
+
+
+def span(name: str, **stats):
+    """A profiler host span ``name`` carrying ``stats`` (``rows``, ...), as
+    a context manager whose ``set_metadata(**stats)`` adds stats known only
+    inside it. Place it once per request or batch, never per row: with the
+    profiler off it costs one check, with it on a TraceMe event."""
+    ann = _annotation()
+    if not ann.is_enabled():
+        return _OFF
+    return ann(name, **stats)
 
 
 @dataclass(frozen=True)

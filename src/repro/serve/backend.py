@@ -30,6 +30,7 @@ from typing import Protocol, runtime_checkable
 import numpy as np
 
 from ..core.forest import ExtraTreesRegressor, predict_flat
+from ..obs.tracing import span
 
 BACKENDS = ("tree-walk", "flat-numpy", "flat-jax", "dense-jax", "pallas")
 #: paths that embed the trees in a complete tree of at most ``dense_depth``
@@ -95,6 +96,11 @@ def calibration_rows(n_rows: int, n_features: int,
                          size=(n_rows, n_features)).astype(np.float32)
 
 
+def pow2_padding(rows: int) -> int:
+    """Rows ``pad_pow2`` appends to a batch of ``rows``."""
+    return (1 << max(rows - 1, 0).bit_length()) - rows
+
+
 def pad_pow2(fn: PredictorBackend) -> PredictorBackend:
     """Pad the batch dim to the next power of two before calling ``fn``.
 
@@ -102,16 +108,23 @@ def pad_pow2(fn: PredictorBackend) -> PredictorBackend:
     arbitrary sizes, so without padding every new size pays a fresh
     compilation. Pow-2 padding bounds the number of compiled variants to
     log2(max_batch). Padding rows replicate the last sample (any valid row
-    works — the pad outputs are sliced off).
+    works — the pad outputs are sliced off). The wrapper's ``padding``
+    (``pow2_padding``) tells the engine that owns it how many rows a call
+    appends.
     """
     def wrapped(X):
         B = X.shape[0]
-        Bp = 1 << max(B - 1, 0).bit_length()
-        if Bp != B:
-            pad = np.broadcast_to(X[-1:], (Bp - B,) + X.shape[1:])
-            X = np.concatenate([X, pad], axis=0)
-        return np.asarray(fn(X))[:B]
+        extra = pow2_padding(B)
+        with span("backend.pad", rows=B, padded=extra):
+            if extra:
+                pad = np.broadcast_to(X[-1:], (extra,) + X.shape[1:])
+                X = np.concatenate([X, pad], axis=0)
+        with span("backend.launch", rows=B):
+            y = fn(X)                  # returns before the chip finishes
+        with span("backend.wait", rows=B):
+            return np.asarray(y)[:B]
     wrapped.__wrapped__ = fn
+    wrapped.padding = pow2_padding
     return wrapped
 
 

@@ -46,6 +46,7 @@ import numpy as np
 
 from ..core.forest import ExtraTreesRegressor
 from ..core.latency import calibrate_backends
+from ..obs.tracing import span
 from .backend import (BACKENDS, PredictorBackend, build_backends,
                       calibration_rows, exact_candidates)
 
@@ -73,6 +74,7 @@ class EngineStats:
     cache_hits: int = 0
     cache_misses: int = 0
     backend_rows: int = 0          # rows actually sent to the backend
+    padded_rows: int = 0           # rows a padding backend appended to them
     batches: int = 0               # backend calls
     flushes_size: int = 0
     flushes_deadline: int = 0
@@ -218,6 +220,10 @@ class ForestEngine:
         X = np.ascontiguousarray(X, dtype=np.float32)
         if X.ndim == 1:
             X = X[None, :]
+        with span("engine.batch", rows=X.shape[0]):
+            return self._predict(X)
+
+    def _predict(self, X: np.ndarray) -> np.ndarray:
         B = X.shape[0]
         out = np.empty(B, dtype=np.float64)
         if B == 0:
@@ -225,7 +231,7 @@ class ForestEngine:
         use_cache = self.config.cache_size > 0
 
         miss_rows: dict[bytes, list[int]] = {}
-        with self._cond:
+        with span("engine.lookup", rows=B), self._cond:
             # snapshot (generation, backend) under the same lock that guards
             # cache reads: cache entries always belong to the snapshot
             # generation (swap clears the cache while holding this lock).
@@ -247,9 +253,12 @@ class ForestEngine:
         if miss_rows:
             rows = [idxs[0] for idxs in miss_rows.values()]
             y = np.asarray(predict_fn(X[rows]), dtype=np.float64)
-            with self._cond:
+            padding = getattr(predict_fn, "padding", None)
+            with span("engine.writeback", rows=len(rows)), self._cond:
                 self.stats.batches += 1
                 self.stats.backend_rows += len(rows)
+                if padding is not None:
+                    self.stats.padded_rows += padding(len(rows))
                 # a swap may have landed while the backend ran: the answers
                 # are still served (uniformly from the OLD generation), but
                 # must not repopulate the new generation's cache.
@@ -357,7 +366,8 @@ class ForestEngine:
         hot path is untouched.  ``labels`` (e.g. ``replica="r0"``) keep
         multiple engines distinct in one registry."""
         for name in ("requests", "predictions", "cache_hits",
-                     "cache_misses", "backend_rows", "batches",
+                     "cache_misses", "backend_rows", "padded_rows",
+                     "batches",
                      "flushes_size", "flushes_deadline", "flushes_manual",
                      "swaps", "shard_drops", "trees_lost"):
             registry.register_fn(f"engine.{name}",
