@@ -2,9 +2,24 @@
 
 Two layouts:
 
-1. ``FlatForest`` (exact): sparse node arrays + gather-based traversal.
-   Works for unbounded-depth trees; jit-compiled; the exact device path on
-   any platform, the TPU included.
+1. ``FlatForest`` (exact): trees of any depth, walked by
+   ``_predict_flat_jax``, the one jitted program of the ``flat-jax``
+   backend. It has two bodies; ``FlatForestJax`` picks one by the platform
+   of the device its tables live on (``core/platform.flat_walk``) and keeps
+   only that body's arrays:
+
+   * ``gathers`` (every platform but the TPU): per level, per-element
+     gathers of the node's feature, threshold, children and of ``x`` from
+     the sparse node arrays. Gathers are cheap on a CPU.
+   * ``levels`` (TPU): ``pack_levels`` lays level ``l`` of every tree out
+     in slots of ``(L, W, T)`` tables; the walk reads a level's node at a
+     lane's slot by compare-and-select over the ``W`` slots, and ``x`` at
+     the node's feature by a select over the features. Dense,
+     lane-parallel work in place of gathers, which a TPU serializes per
+     element.
+
+   Both bodies compare ``x <= threshold`` in float32 and average the same
+   leaf matrix, so their answers are bit-identical.
 
 2. ``DenseForest``: every tree is embedded into a *complete* binary tree
    of fixed depth D (child index = 2i+1 / 2i+2, no child pointers).
@@ -24,13 +39,63 @@ import jax.numpy as jnp
 import numpy as np
 
 from .forest import ExtraTreesRegressor, FlatForest
+from .platform import flat_walk
 
 
 # ---------------------------------------------------------------- flat (exact)
 
-@partial(jax.jit, static_argnames=("max_depth",))
-def _predict_flat_jax(feature, threshold, left, right, value, roots, x,
-                      max_depth: int):
+def pack_levels(flat: FlatForest):
+    """Level-packed node tables ``(feature, threshold, child, value)``, each
+    ``(L, W, T)``: ``L = max_depth + 1`` levels, ``W`` the widest level of
+    any tree rounded up to 8, ``T`` trees.
+
+    Level ``l`` of tree ``t`` fills slots ``[0, n)`` of ``[l, :, t]``. The
+    ``k``-th internal node of a level, in slot order, has its children in
+    slots ``2k`` (left) and ``2k + 1`` (right) of the next level, and
+    ``child`` holds ``2k``. ``feature`` is -1 at leaves and padding;
+    ``value`` holds a leaf's value and 0 elsewhere; ``threshold`` and
+    ``child`` are 0 where unused. Built from the forest's global child
+    indices, one level at a time, whatever the order of the nodes.
+    """
+    n_trees = flat.n_trees
+    n_levels = flat.max_depth + 1
+    node = flat.roots.astype(np.int64)
+    tree = np.arange(n_trees)            # sorted by (tree, slot) throughout
+    slot = np.zeros(n_trees, dtype=np.int64)
+    levels = []
+    while node.size:
+        if len(levels) == n_levels:
+            raise ValueError(f"a tree is deeper than max_depth "
+                             f"{flat.max_depth}")
+        inner = flat.feature[node] >= 0
+        parents = tree[inner]
+        rank = np.arange(parents.size) - np.searchsorted(parents, parents)
+        levels.append((node, tree, slot, inner, 2 * rank))
+        node = np.stack([flat.left[node[inner]], flat.right[node[inner]]],
+                        axis=1).ravel()
+        tree = np.repeat(parents, 2)
+        slot = (2 * rank[:, None] + np.arange(2)).ravel()
+    width = max(int(lv[2].max()) + 1 for lv in levels)
+    width = -(-width // 8) * 8
+    shape = (n_levels, width, n_trees)
+    feature = np.full(shape, -1, dtype=np.int32)
+    threshold = np.zeros(shape, dtype=np.float32)
+    child = np.zeros(shape, dtype=np.int32)
+    value = np.zeros(shape, dtype=np.float32)
+    for lvl, (node, tree, slot, inner, kids) in enumerate(levels):
+        at = (lvl, slot[inner], tree[inner])
+        feature[at] = flat.feature[node[inner]]
+        threshold[at] = flat.threshold[node[inner]]
+        child[at] = kids
+        leaf = ~inner
+        value[lvl, slot[leaf], tree[leaf]] = flat.value[node[leaf]]
+    return feature, threshold, child, value
+
+
+def _gather_leaves(feature, threshold, left, right, value, roots, x,
+                   max_depth: int):
+    """(B, T) leaf value of each row in each tree, by per-element gathers
+    from the flat node arrays."""
     B = x.shape[0]
     T = roots.shape[0]
     cur = jnp.broadcast_to(roots[None, :], (B, T)).astype(jnp.int32)
@@ -45,21 +110,77 @@ def _predict_flat_jax(feature, threshold, left, right, value, roots, x,
         return jnp.where(active, nxt, cur)
 
     cur = jax.lax.fori_loop(0, max_depth, body, cur)
-    return jnp.take(value, cur).mean(axis=1)
+    return jnp.take(value, cur)
+
+
+def _select(at, table):
+    """``table``'s entry where ``at`` holds, reduced over axis 0. At most one
+    entry along that axis holds, so the int32 sum of the bit patterns, the
+    rest zeros, is that entry to the bit (0 where none holds)."""
+    bits = jax.lax.bitcast_convert_type(table, jnp.int32)
+    got = jnp.where(at, bits, 0).sum(axis=0, dtype=jnp.int32)
+    return jax.lax.bitcast_convert_type(got, table.dtype)
+
+
+def _level_leaves(feature, threshold, child, value, x, max_depth: int):
+    """(B, T) leaf value of each row in each tree, by compare-and-select
+    over the slots of ``pack_levels``' tables, one level at a time."""
+    B, F = x.shape
+    _, W, T = feature.shape
+    slots = jnp.arange(W, dtype=jnp.int32)[:, None, None]
+    feats = jnp.arange(F, dtype=jnp.int32)[:, None, None]
+    xs = x.T[:, :, None]                              # (F, B, 1)
+
+    def body(lvl, state):
+        cur, acc = state                  # slot, or -1 past the leaf; (B, T)
+        at = cur[None] == slots                       # (W, B, T)
+        feat, thr, kid, val = (_select(at, t[lvl][:, None, :])
+                               for t in (feature, threshold, child, value))
+        xv = _select(feat[None] == feats, xs)         # x[b, feat[b, t]]
+        live = cur >= 0
+        acc = jnp.where(live & (feat < 0), val, acc)
+        cur = jnp.where(live & (feat >= 0),
+                        kid + jnp.where(xv <= thr, 0, 1), -1)
+        return cur, acc
+
+    state = (jnp.zeros((B, T), jnp.int32), jnp.zeros((B, T), jnp.float32))
+    _, acc = jax.lax.fori_loop(0, max_depth + 1, body, state)
+    return acc
+
+
+_LEAVES = {"gathers": _gather_leaves, "levels": _level_leaves}
+
+
+@partial(jax.jit, static_argnames=("max_depth", "walk"))
+def _predict_flat_jax(*args, max_depth: int, walk: str = "gathers"):
+    """The ``flat-jax`` program: ``args`` are the walk's node arrays, then
+    ``x`` (B, F) float32; returns (B,) float32. ``walk="gathers"`` takes
+    ``FlatForest``'s ``(feature, threshold, left, right, value, roots)``,
+    ``walk="levels"`` the four tables of ``pack_levels``."""
+    *nodes, x = args
+    return _LEAVES[walk](*nodes, x, max_depth).mean(axis=1)
 
 
 class FlatForestJax:
-    """jit-wrapped exact inference over a FlatForest."""
+    """jit-wrapped exact inference over a FlatForest on the first device.
+    ``walk`` follows that device's platform (``core/platform.flat_walk``);
+    ``arrays`` holds that walk's node arrays and no others."""
 
     def __init__(self, forest: FlatForest):
-        self.arrays = tuple(jnp.asarray(a) for a in (
-            forest.feature, forest.threshold, forest.left, forest.right,
-            forest.value, forest.roots))
+        device = jax.devices()[0]
+        self.walk = flat_walk(device)
+        if self.walk == "levels":
+            arrays = pack_levels(forest)
+        else:
+            arrays = (forest.feature, forest.threshold, forest.left,
+                      forest.right, forest.value, forest.roots)
+        self.arrays = tuple(jax.device_put(a, device) for a in arrays)
         self.max_depth = int(forest.max_depth)
 
     def __call__(self, x: np.ndarray | jax.Array) -> jax.Array:
         x = jnp.asarray(x, dtype=jnp.float32)
-        return _predict_flat_jax(*self.arrays, x, max_depth=self.max_depth)
+        return _predict_flat_jax(*self.arrays, x, max_depth=self.max_depth,
+                                 walk=self.walk)
 
 
 # ------------------------------------------------------------- dense (TPU path)

@@ -6,7 +6,8 @@ same quantity for every inference path in this repo:
 
   * ``tree-walk``  : per-tree numpy traversal (the paper's deployment path)
   * ``flat-numpy`` : vectorized flattened-forest numpy
-  * ``flat-jax``   : jit-compiled gather traversal
+  * ``flat-jax``   : jit-compiled exact walk: per-element gathers, or selects
+                     over level-packed tables on a TPU
   * ``dense-jax``  : complete-tree layout (the Pallas kernel's oracle)
   * ``pallas``     : the dense-layout Pallas kernel (interpreted off-TPU)
 

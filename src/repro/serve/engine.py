@@ -56,6 +56,12 @@ __all__ = ["BACKENDS", "EngineConfig", "EngineStats", "ForestEngine",
 
 # -------------------------------------------------------------------- engine
 
+def _walks_levels(fn: PredictorBackend) -> bool:
+    """Whether a backend, or the path a ``pad_pow2`` wrapper wraps, is a
+    ``FlatForestJax`` walking level-packed tables (``core/forest_jax.py``)."""
+    return getattr(getattr(fn, "__wrapped__", fn), "walk", None) == "levels"
+
+
 @dataclass
 class EngineConfig:
     backend: str = "auto"          # one of BACKENDS, or "auto"
@@ -75,6 +81,7 @@ class EngineStats:
     cache_misses: int = 0
     backend_rows: int = 0          # rows actually sent to the backend
     padded_rows: int = 0           # rows a padding backend appended to them
+    level_walk_rows: int = 0       # backend + padded rows a level walk took
     batches: int = 0               # backend calls
     flushes_size: int = 0
     flushes_deadline: int = 0
@@ -121,6 +128,7 @@ class ForestEngine:
             raise RuntimeError("no backend could be built")
         self.backend = self._select(self._backends, calibration_X)
         self._predict_fn = self._backends[self.backend]
+        self._level_walk = _walks_levels(self._predict_fn)
 
         self._generation = 0
         self._cache: OrderedDict[bytes, float] = OrderedDict()
@@ -201,6 +209,7 @@ class ForestEngine:
             self._backends = backends
             self.backend = name
             self._predict_fn = backends[name]
+            self._level_walk = _walks_levels(self._predict_fn)
             self._cache.clear()
             self._generation += 1
             self.stats.generation = self._generation
@@ -237,6 +246,7 @@ class ForestEngine:
             # generation (swap clears the cache while holding this lock).
             gen = self._generation
             predict_fn = self._predict_fn
+            level_walk = self._level_walk
             for i in range(B):
                 key = X[i].tobytes()
                 if use_cache and key in self._cache:
@@ -254,11 +264,13 @@ class ForestEngine:
             rows = [idxs[0] for idxs in miss_rows.values()]
             y = np.asarray(predict_fn(X[rows]), dtype=np.float64)
             padding = getattr(predict_fn, "padding", None)
+            padded = padding(len(rows)) if padding is not None else 0
             with span("engine.writeback", rows=len(rows)), self._cond:
                 self.stats.batches += 1
                 self.stats.backend_rows += len(rows)
-                if padding is not None:
-                    self.stats.padded_rows += padding(len(rows))
+                self.stats.padded_rows += padded
+                if level_walk:
+                    self.stats.level_walk_rows += len(rows) + padded
                 # a swap may have landed while the backend ran: the answers
                 # are still served (uniformly from the OLD generation), but
                 # must not repopulate the new generation's cache.
@@ -367,7 +379,7 @@ class ForestEngine:
         multiple engines distinct in one registry."""
         for name in ("requests", "predictions", "cache_hits",
                      "cache_misses", "backend_rows", "padded_rows",
-                     "batches",
+                     "level_walk_rows", "batches",
                      "flushes_size", "flushes_deadline", "flushes_manual",
                      "swaps", "shard_drops", "trees_lost"):
             registry.register_fn(f"engine.{name}",

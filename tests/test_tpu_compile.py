@@ -76,6 +76,22 @@ def test_flat_jax_compiles_for_v5e_at_depth_40(one_chip):
     assert compiled.as_text()
 
 
+@pytest.mark.parametrize("batch", [1, 4096])
+def test_flat_jax_level_walk_compiles_for_v5e(one_chip, batch):
+    """The TPU's body of the same program, over the deep forest's tables:
+    36 levels of 104 slots. The select over the slots is fused: no
+    (slots, batch, trees) array is written to the device's memory."""
+    levels, slots = 36, 104
+    i32 = _spec((levels, slots, TREES), jnp.int32, one_chip)
+    f32 = _spec((levels, slots, TREES), jnp.float32, one_chip)
+    compiled = _predict_flat_jax.lower(
+        i32, f32, i32, f32, _spec((batch, FEATURES), jnp.float32, one_chip),
+        max_depth=levels - 1, walk="levels").compile()
+    assert compiled.as_text()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 4 * slots * batch * TREES
+
+
 def test_dense_jax_compiles_for_v5e_at_depth_10(one_chip):
     nodes = 2 ** 11 - 1
     compiled = _predict_dense_jax.lower(
