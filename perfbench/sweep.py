@@ -7,8 +7,9 @@ at which its latency stays steady and no backlog grows.
 One server serves one seed's forest; each rate gets a window of its own.
 Per rate it prints a JSON line: p50 and p95 (ms), the median latency of the
 last fifth of the requests over that of the first fifth (about 1 when no
-backlog grows), how late the generator ran, and requests not answered. The
-rate written into the mix's file is 0.8 of the highest steady rate.
+backlog grows), how late the generator ran (99th percentile and most),
+and requests not answered. An open-loop cell runs at about four fifths of
+its mix's highest steady rate, where its tails are end-to-end metrics.
 """
 from __future__ import annotations
 
@@ -52,6 +53,7 @@ def main(argv=None) -> int:
                 "growth": float(np.median(lat[-fifth:])
                                 / np.median(lat[:fifth])),
                 "generator_p99_ms": late["p99_ms"],
+                "generator_max_ms": late["max_ms"],
                 "unanswered": int(sum(x.error is not None for x in sent))}),
                 flush=True)
         run.finish(s)

@@ -17,6 +17,7 @@ ROOT = Path(run.__file__).resolve().parents[1]
 
 CHILD = """
 import sys
+import time
 sys.path[:0] = {paths!r}
 import numpy as np
 import jax.numpy as jnp
@@ -40,6 +41,8 @@ def broken(self, x):
         *nodes, roots = self.arrays
         return fj._predict_flat_jax(*nodes, roots[:len(roots) // 2], x,
                                     max_depth=self.max_depth)
+    if fault == "half_rows":            # a slow path, so one-row requests
+        time.sleep(0.05)                # queue and merge into batches
     y = np.array(sound(self, x))
     if fault == "half_rows":            # half the batch gets the other's mean
         y[len(y) // 2:] = y[:max(len(y) // 2, 1)].mean()
@@ -57,13 +60,8 @@ sys.exit(server.main(sys.argv[1:], require_tpu=False))
 @pytest.fixture(scope="module")
 def bench(tmp_path_factory):
     """BENCHMARK.json with both configurations cut to 16 trees of depth
-    at most 8, served by flat-jax, and cells for the open-loop mixes."""
+    at most 8, served by flat-jax."""
     doc = json.loads((ROOT / "BENCHMARK.json").read_text())
-    have = {w["name"] for w in doc["workloads"]}
-    doc["workloads"] += [{"name": f"et512-deep.{mix}", "config": "et512-deep",
-                          "traffic": mix, "chips": 1}
-                         for mix in ("single", "repeat")
-                         if f"et512-deep.{mix}" not in have]
     cfg = json.loads((ROOT / "perfbench/configs/et512-deep.json").read_text())
     cfg.update(n_estimators=16, max_depth=8)
     path = tmp_path_factory.mktemp("cfg") / "tiny.json"
@@ -87,7 +85,8 @@ def one_run(bench, fault, workload="et512-deep.batch", trace=0):
 
 @pytest.mark.parametrize("workload,trace", [
     ("et512-deep.batch", 0), ("et512-deep.single", 0),
-    ("et512-deep.repeat", 0), ("et512-d10.batch", 1)])
+    ("et512-deep.repeat", 0), ("et512-d10.batch", 1),
+    ("et512-deep.single", 1), ("et512-deep.repeat", 1)])
 def test_sound_run_is_correct(bench, workload, trace):
     line = one_run(bench, None, workload, trace)
     assert line["correct"], line["checks"]
@@ -107,6 +106,19 @@ def test_sound_run_is_correct(bench, workload, trace):
                                    "bf16_control"])
 def test_broken_path_is_not_correct(bench, fault):
     line = one_run(bench, fault)
+    assert not line["correct"], line["checks"]
+    assert line["checks"]["max_rel_err"]["value"] > run.MAX_REL_ERR
+
+
+@pytest.mark.parametrize("workload", ["et512-deep.single",
+                                      "et512-deep.repeat"])
+@pytest.mark.parametrize("fault", ["state_unchanged", "half_trees",
+                                   "half_rows", "altered_answer",
+                                   "bf16_control"])
+def test_broken_path_is_not_correct_in_one_row_cells(bench, fault, workload):
+    """In ``repeat`` the cache hands back what the broken path made; half
+    the batch left out needs merged calls, which its slow path makes."""
+    line = one_run(bench, fault, workload)
     assert not line["correct"], line["checks"]
     assert line["checks"]["max_rel_err"]["value"] > run.MAX_REL_ERR
 

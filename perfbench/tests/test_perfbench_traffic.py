@@ -57,7 +57,10 @@ def test_fresh_rows_never_repeat(name):
 
 
 def test_repeat_draws_catalog_rows_zipf_with_a_few_fresh():
-    mix = traffic.load_mix("repeat")
+    """The generator's Zipf draw, which a mix may name, over the repeat
+    mix's arrivals."""
+    mix = dict(traffic.load_mix("repeat"), draw="zipf", zipf_s=1.1,
+               fresh=0.02)
     rows = traffic.Drawer(mix, CATALOG, SEED).rows(
         20000, traffic.rng_for(SEED, 1, 3))
     assert rows.fresh.sum() == round(mix["fresh"] * 20000)
@@ -66,6 +69,18 @@ def test_repeat_draws_catalog_rows_zipf_with_a_few_fresh():
     counts = np.sort(np.bincount(rows.idx, minlength=len(CATALOG)))[::-1]
     # Zipf 1.1: the most drawn row is drawn far more than the median one
     assert counts[0] > 20 * max(counts[len(counts) // 2], 1)
+
+
+def test_repeat_sends_every_catalog_row_as_it_is():
+    """Every kernel is one priced before: no fresh row, no row favoured,
+    and in a window's worth of draws every catalog row comes up."""
+    mix = traffic.load_mix("repeat")
+    rows = traffic.Drawer(mix, CATALOG, SEED).rows(
+        20000, traffic.rng_for(SEED, 1, 3))
+    assert not rows.fresh.any()
+    np.testing.assert_array_equal(rows.X, CATALOG[rows.idx])
+    counts = np.bincount(rows.idx, minlength=len(CATALOG))
+    assert counts.min() > 0 and counts.max() < 3 * counts.mean()
 
 
 def test_dispatch_sizes_cover_every_merge():

@@ -59,6 +59,8 @@ def test_busy_programs_and_ops():
     assert [(c["rows"], c["whole"]) for c in calls] == [
         (8, True), (64, True), (32, False)]
     assert [c["inside"] for c in calls] == pytest.approx([1.0, 1.0, 0.5])
+    assert [c["host_s"] for c in calls] == pytest.approx(
+        [350e-9, 140e-9, 120e-9])
     assert [c["programs"][PROG]["seconds"] for c in calls] == pytest.approx(
         [200e-9, 100e-9, 100e-9])
     assert all(c["programs"][PROG]["runs"] == 1 for c in calls)
@@ -81,6 +83,28 @@ def test_forest_readers_count_rows_and_time_over_the_same_calls():
     mfu = readings.reader("forest_mfu.batch")(run)
     assert mfu == pytest.approx(
         100 * (least(8) + least(64) + 0.5 * least(32)) / 1000e-9)
+
+
+def test_one_row_cell_readers_over_the_same_calls():
+    from perfbench import readings, work
+    run = {"trace": xplane.reduce(fake_trace()), "device_kind": "TPU v5 lite",
+           "work": {"compares_per_row": 10.0, "nodes": 1000, "features": 12}}
+    # the two calls wholly inside: 300 ns of device time, per call
+    per_call = readings.reader("forest_device_us.single")(run)
+    assert per_call == pytest.approx(300e-9 / 2 * 1e6)
+    for name, same in (("forest_roofline.single", "forest_roofline"),
+                       ("device_idle_share.single",
+                        "device_idle_share.batch")):
+        assert readings.reader(name)(run) == readings.reader(same)(run)
+    assert readings.reader("device_idle_share.single")(run) == (
+        pytest.approx(70.0))
+    # the same two calls' least time over their 350 + 140 ns of host time,
+    # which bounds the roofline over their 300 ns of device time
+    least = sum(work.least_seconds(rows, 1, 10.0, 1000, 12, "TPU v5 lite")
+                for rows in (8, 64))
+    mfu = readings.reader("forest_mfu.single")(run)
+    assert mfu == pytest.approx(100 * least / 490e-9)
+    assert mfu < readings.reader("forest_roofline.single")(run)
 
 
 def test_idle_gaps_are_named_by_the_innermost_host_event():

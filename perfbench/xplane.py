@@ -14,8 +14,9 @@ are relative to the session's start. On each TPU device plane:
   calls       the server's engine calls (host events ``CALL``, each with
               the stat ``rows``) that overlap the window and ran a program
               (line ``XLA Modules``) on the chip: rows, whether the call
-              lies wholly inside the window, the share of its host time
-              inside, and per program its device seconds and runs. A
+              lies wholly inside the window, its host seconds and the
+              share of them inside, and per program its device seconds
+              and runs. A
               program run belongs to the call whose host event overlaps
               it most, so rows and device time are counted over the same
               calls
@@ -140,6 +141,7 @@ def _calls(pd, modules: list, lo: float, hi: float) -> list:
             continue
         c = calls.setdefault(i, {
             "rows": rows, "whole": bool(a >= lo and b <= hi),
+            "host_s": (b - a) * 1e-9,
             "inside": (min(b, hi) - max(a, lo)) / max(b - a, 1.0),
             "programs": {}})
         p = c["programs"].setdefault(name, {"seconds": 0.0, "runs": 0})
