@@ -15,9 +15,14 @@ function ``read(run) -> float | None``. ``run`` is a dict:
     trace       ``xplane.reduce_dir``'s summary of the profiler's trace
     counters    frontend, engine and pool counters: the snapshot taken
                 just before the window's close minus the one just after
-                its open
+                its open. ``engine`` is summed field by field over every
+                engine the server holds (one, or one per entry of the
+                configuration's ``forests``), ``engines`` holds each
+                one's by the column its forest was fitted on (``y`` for
+                the one forest)
     latency     the frontend's ``latency_summary()`` at the stop
-    work        compares per row, real nodes, features of the forest
+    work        compares per row, real nodes, features of a forest call:
+                the one forest's, or the mean over the forests
     device_kind the chip's ``device_kind``
 
 A reader that finds nothing to read returns ``None`` and the metric is left

@@ -4,7 +4,7 @@ at which its latency stays steady and no backlog grows.
     python3 perfbench/sweep.py --workload et512-deep.single --seed 7 \
         --seconds 5 --rates 500,1000,2000,4000
 
-One server serves one seed's forest; each rate gets a window of its own.
+One server serves the cell's forests; each rate gets a window of its own.
 Per rate it prints a JSON line: p50 and p95 (ms), the median latency of the
 last fifth of the requests over that of the first fifth (about 1 when no
 backlog grows), how late the generator ran (99th percentile and most),
@@ -25,7 +25,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 from perfbench import run, traffic  # noqa: E402
 
 
-def main(argv=None) -> int:
+def main(argv=None, *, bench=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, required=True)
@@ -33,7 +33,8 @@ def main(argv=None) -> int:
     ap.add_argument("--rates", required=True)
     args = ap.parse_args(argv)
     args.trace = 0
-    bench = json.loads((run.ROOT / "BENCHMARK.json").read_text())
+    bench = bench if bench is not None else json.loads(
+        (run.ROOT / "BENCHMARK.json").read_text())
     cell = run.load_cell(bench, args.workload)
     s = run.start(args, cell)
     try:

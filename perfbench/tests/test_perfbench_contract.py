@@ -1,5 +1,6 @@
 """BENCHMARK.json and the files it names agree: every cell, configuration,
-mix and metric is found by its name."""
+mix and metric is found by its name, and every forest a configuration
+names is found in its targets file, on a device with a DVFS grid."""
 import json
 import re
 from pathlib import Path
@@ -12,6 +13,10 @@ ROOT = Path(run.__file__).resolve().parents[1]
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 METRICS = BENCH["end_to_end"] + BENCH["per_layer"]
+#: Configurations that name their forests: the benchmark's and the tests'.
+FORESTS = [p for p in sorted([*(ROOT / "perfbench/configs").glob("*.json"),
+                              *(ROOT / "perfbench/tests/data").glob("*.json")])
+           if "forests" in json.loads(p.read_text())]
 
 
 @pytest.mark.parametrize("m", METRICS, ids=lambda m: m["name"])
@@ -39,3 +44,22 @@ def test_names_are_unique_and_layers_named():
     for m in BENCH["per_layer"]:
         assert m["layer"] and "\n" not in m["layer"]
         assert set(m["workloads"]) <= {w["name"] for w in BENCH["workloads"]}
+
+
+@pytest.mark.parametrize("path", FORESTS, ids=lambda p: p.name)
+def test_named_forests_are_found_in_the_targets(path):
+    cfg = json.loads(path.read_text())
+    targets = json.loads((ROOT / cfg["targets"]).read_text())
+    catalog = json.loads((ROOT / cfg["catalog"]).read_text())
+    assert targets["rows"] == catalog["rows"]
+    seen = set()
+    for e in cfg["forests"]:
+        assert e["target"] in ("time_us", "power_w")
+        assert e["column"] in targets["columns"], e
+        assert len(targets["columns"][e["column"]]) == len(catalog["rows"])
+        assert (e["device"], e["target"]) not in seen, e
+        seen.add((e["device"], e["target"]))
+        grid = targets["devices"][e["device"]]["freq_grid"]
+        assert grid and all(f > 0 for f in grid), e
+    devices = {d for d, _ in seen}
+    assert seen == {(d, t) for d in devices for t in ("time_us", "power_w")}

@@ -1,10 +1,13 @@
 """The readers of the frontend's wait and merging, the backend's padding
 and the engine cache's hits: counter deltas of the traced window, and
-nothing where the program has no such counter or counted nothing. And the
-median latency, by nearest rank."""
+nothing where the program has no such counter or counted nothing. The
+server's engine counters, summed over its engines. And the median latency,
+by nearest rank."""
+import dataclasses
+
 import pytest
 
-from perfbench import readings
+from perfbench import readings, server
 
 WAIT, PADDED = "frontend_wait_ms.batch", "padded_row_share.batch"
 ONE_ROW = ("rows_per_dispatch.single", "frontend_wait_ms.single",
@@ -57,3 +60,32 @@ def test_one_row_cell_readers_read_nothing_without_counts(name):
 ], ids=["odd", "even", "unanswered"])
 def test_p50_is_the_nearest_rank_median(latency_s, want_ms):
     assert readings.reader("p50_ms")({"latency_s": latency_s}) == want_ms
+
+
+class Stub:
+    """Something with a counters snapshot, as engine, frontend and pool."""
+
+    def __init__(self, stats):
+        self.stats = stats
+
+    def stats_snapshot(self):
+        return self.stats
+
+    def latency_summary(self):
+        return {}
+
+
+def test_engine_counters_are_summed_over_engines():
+    from repro.cluster.frontend import FrontendStats
+    from repro.cluster.replicas import PoolStats
+    from repro.serve.engine import EngineStats
+    front, pool = Stub(FrontendStats()), Stub(PoolStats())
+    a = EngineStats(batches=2, backend_rows=10, padded_rows=6)
+    b = EngineStats(batches=3, backend_rows=7, cache_hits=4)
+    one = server.counters(front, {"y": Stub(a)}, pool)
+    assert one["engine"] == dataclasses.asdict(a)
+    assert one["engines"] == {"y": dataclasses.asdict(a)}
+    two = server.counters(front, {"t": Stub(a), "p": Stub(b)}, pool)
+    assert two["engine"] == dataclasses.asdict(EngineStats(
+        batches=5, backend_rows=17, padded_rows=6, cache_hits=4))
+    assert list(two["engines"]) == ["t", "p"]

@@ -85,3 +85,42 @@ def test_bf16_control_fails_the_limit_and_float32_passes():
                        for t in range(f.n_trees)])
     f32 = leaves.astype(np.float32).mean(axis=0, dtype=np.float32)
     assert np.max(np.abs(f32 - want) / scale) < run.MAX_REL_ERR / 10
+
+
+TARGETS = json.loads((run.HERE / "data" / "suite_targets.json").read_text())
+
+
+@pytest.mark.parametrize("max_depth", [None, 6])
+def test_config_without_forests_fits_the_one_forest_from_y(max_depth):
+    """Stream 0 of ``fit_seed`` on the catalog's ``y``, as before
+    configurations could name forests."""
+    cfg = {"n_estimators": 6, "max_depth": max_depth, "fit_seed": 1,
+           "catalog": "perfbench/data/suite_catalog.json"}
+    (got,) = run.fit_forests(cfg, X).values()
+    want = pf.fit(X, Y, 6, max_depth, np.random.default_rng([1, 0]))
+    for k in (*pf.FIELDS, "offsets"):
+        np.testing.assert_array_equal(getattr(got, k), getattr(want, k))
+
+
+def test_named_forests_are_fitted_on_their_columns_from_their_streams():
+    entries = [{"device": "tpu-v4", "target": t, "column": f"tpu-v4/{t}"}
+               for t in ("time_us", "power_w")]
+    cfg = {"n_estimators": 3, "max_depth": 4, "fit_seed": 1,
+           "catalog": "perfbench/data/suite_catalog.json",
+           "targets": "perfbench/data/suite_targets.json",
+           "forests": entries}
+    got = run.fit_forests(cfg, X)
+    assert list(got) == ["tpu-v4/time_us", "tpu-v4/power_w"]
+    alone = run.fit_forests(dict(cfg, forests=entries[1:]), X)
+    np.testing.assert_array_equal(got["tpu-v4/power_w"].threshold,
+                                  alone["tpu-v4/power_w"].threshold)
+    power = np.asarray(TARGETS["columns"]["tpu-v4/power_w"])
+    np.testing.assert_allclose(pf.walk(got["tpu-v4/power_w"], X).mean(),
+                               power.mean(), rtol=1e-6)
+
+
+def test_targets_hold_the_catalog_target_on_the_same_rows():
+    assert TARGETS["rows"] == CATALOG["rows"]
+    assert TARGETS["columns"]["tpu-v5e/time_us"] == CATALOG["y"]
+    assert all(len(c) == len(CATALOG["y"])
+               for c in TARGETS["columns"].values())

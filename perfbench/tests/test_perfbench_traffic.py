@@ -88,6 +88,8 @@ def test_dispatch_sizes_cover_every_merge():
         32, 64, 128, 256, 512, 1024, 2048, 4096]
     assert traffic.dispatch_sizes(traffic.load_mix("single"), 64) == [
         1, 2, 4, 8, 16, 32, 64]
+    queues = {"loop": "open", "clients": 8, "rows": {"min": 16, "max": 256}}
+    assert traffic.dispatch_sizes(queues, 1) == [16, 32, 64, 128, 256]
 
 
 def test_negative_seed_is_refused():

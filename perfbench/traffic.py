@@ -13,6 +13,11 @@ A mix file (``mixes/<name>.json``) holds:
              ``1 + perturb * N(0, 1)``, so no two fresh rows repeat.
   fresh      share of the rows that are fresh (default 1); the others are
              catalog rows as they are, which the engine's cache can answer.
+  op         what a request asks: "predict" (the default), each row's
+             prediction; or "schedule", its rows a queue of kernels placed
+             on the configuration's devices under ``objective``
+             ("makespan", "energy" or "edp") and, where given,
+             ``deadline_ms``.
 
 The work does not depend on the seed: a closed-loop client cycles through
 a fixed set of sizes in a fixed, spread-out order (``closed_sizes``),
@@ -56,9 +61,9 @@ def size_grid(mix: dict) -> np.ndarray:
 
 
 def dispatch_sizes(mix: dict, dispatch_batch: int) -> list[int]:
-    """Every power-of-two batch the frontend can hand the engine under this
-    mix: merges of up to ``dispatch_batch`` queued requests (``clients`` of
-    them in a closed loop)."""
+    """Every power-of-two batch an engine can be handed under this mix:
+    merges of up to ``dispatch_batch`` queued requests (``clients`` of
+    them in a closed loop); 1 where requests are never merged."""
     most = mix["rows"]["max"] * (mix["clients"] if mix["loop"] == "closed"
                                  else dispatch_batch)
     lo = (mix["rows"]["min"] - 1).bit_length()
